@@ -113,9 +113,22 @@ def spawn_forced_device_child(module: str, devices: int, args: list,
     """Run ``python -m benchmarks.<module> --child ...`` in a subprocess
     with ``--xla_force_host_platform_device_count`` (which must be set
     before jax imports) and parse the tagged JSON result line — the
-    shared protocol of the multi-device benchmark children."""
+    shared protocol of the multi-device benchmark children.
+
+    CPU backend only: forced host devices exist only there, and on a chip
+    the parent process already holds the accelerator, so a child that
+    needs it would fail or hang.  There :func:`run_device_arm` runs the
+    arm in this process instead."""
     import subprocess
     import sys
+
+    import jax
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"forced-device children run on the CPU backend only, not "
+            f"{backend!r}: run the multi-device arm in one process over "
+            f"jax.devices()")
     env = tuned_child_env(devices)
     root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
     env["PYTHONPATH"] = os.path.join(root, "src") + os.pathsep \
@@ -127,9 +140,46 @@ def spawn_forced_device_child(module: str, devices: int, args: list,
     if out.returncode != 0:
         raise RuntimeError(f"{module} child (devices={devices}) failed:\n"
                            + out.stderr[-2000:])
-    line = [l for l in out.stdout.splitlines()
-            if l.startswith(result_tag)][0]
+    return _tagged_result(out.stdout, result_tag)
+
+
+def _tagged_result(stdout: str, result_tag: str) -> dict:
+    line = [l for l in stdout.splitlines() if l.startswith(result_tag)][0]
     return json.loads(line[len(result_tag):])
+
+
+def arm_devices(devices: int) -> int:
+    """Device count of a multi-device arm, checked against what JAX has:
+    all of them in a forced-device child, the first ``devices`` of the
+    chips when the arm runs in-process (``jax.make_mesh`` takes a prefix)."""
+    import jax
+    have = len(jax.devices())
+    if have < devices:
+        raise RuntimeError(f"arm needs {devices} devices, JAX has {have}")
+    return devices
+
+
+def run_device_arm(module: str, devices: int, args: list,
+                   result_tag: str) -> dict:
+    """Run one multi-device arm of ``benchmarks.<module>`` on ``devices``
+    devices and return its tagged JSON result.
+
+    On the CPU backend the arm is a forced-host-device child
+    (:func:`spawn_forced_device_child`).  Anywhere else this process holds
+    the chips, so the same ``--child`` body runs here, over the first
+    ``devices`` of ``jax.devices()``."""
+    import contextlib
+    import importlib
+    import io
+
+    import jax
+    if jax.default_backend() == "cpu":
+        return spawn_forced_device_child(module, devices, args, result_tag)
+    buf = io.StringIO()
+    with contextlib.redirect_stdout(buf):
+        importlib.import_module(f"benchmarks.{module}").main(
+            ["--child", "--devices", str(devices)] + [str(a) for a in args])
+    return _tagged_result(buf.getvalue(), result_tag)
 
 
 def queues_for(area: str, n: int, km: float, seed0: int = 0):

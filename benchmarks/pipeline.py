@@ -41,7 +41,7 @@ def _child_main(args) -> None:
     import jax
     import numpy as np
 
-    from benchmarks.common import RATE_SCALE
+    from benchmarks.common import RATE_SCALE, arm_devices
     from repro.core.environment import Area, EnvironmentParams, \
         build_task_queue
     from repro.core.hmai import HMAIPlatform
@@ -55,8 +55,7 @@ def _child_main(args) -> None:
         tasks_to_arrays
     from repro.launch.mesh import make_platform_mesh
 
-    n_dev = len(jax.devices())
-    assert n_dev == args.devices, (n_dev, args.devices)
+    n_dev = arm_devices(args.devices)
     S = args.stages
 
     def drain(ta: TaskArrays, tasks: int) -> TaskArrays:
@@ -125,7 +124,7 @@ def _child_main(args) -> None:
 
     # parity 2: stage-sharded mesh run == flattened (records and combined
     # final state bit-exact; ring hops via ppermute)
-    mesh = make_platform_mesh(S)
+    mesh = make_platform_mesh(S, devices=n_dev)
     sharded = make_sharded_pipeline_fn(spec, planS, mesh, policy="eft")
     (stS, _, rcS), t_shard = best_of(
         lambda: jax.block_until_ready(sharded(None, batch)), args.iters)
@@ -161,8 +160,8 @@ def _child_main(args) -> None:
 
 def _spawn(devices: int, stages: int, routes: int, tasks: int,
            iters: int) -> dict:
-    from benchmarks.common import spawn_forced_device_child
-    return spawn_forced_device_child(
+    from benchmarks.common import run_device_arm
+    return run_device_arm(
         "pipeline", devices,
         ["--stages", stages, "--routes", routes, "--tasks", tasks,
          "--iters", iters],

@@ -42,6 +42,8 @@ def main(argv=None) -> int:
     ap.add_argument("--only", default=None, help="run a single module")
     args = ap.parse_args(argv)
     quick = not args.full
+    from repro.compat import enable_compile_cache
+    enable_compile_cache()
 
     mods = [args.only] if args.only else MODULES
     print("name,us_per_call,derived")

@@ -2,8 +2,10 @@
 
 The scan engine is embarrassingly parallel over routes, so the shard_map
 variant should scale until the per-device lane width stops covering the
-scan-step overhead.  Each measurement runs in a subprocess because
-``--xla_force_host_platform_device_count`` must be set before jax imports.
+scan-step overhead.  On the CPU backend each measurement runs in a
+subprocess, because ``--xla_force_host_platform_device_count`` must be set
+before jax imports; on a chip it runs in this process over the first
+device counts of ``jax.devices()`` (``common.run_device_arm``).
 
 Every child also replays the same batch through the plain single-device
 vmapped scan and checks fp32 parity (identical placements, metrics within
@@ -24,13 +26,14 @@ RESULT_TAG = "SHARDED_RESULT "
 
 
 def _child_main(args) -> None:
-    """Runs inside a subprocess with the forced device count already set."""
+    """One device-count arm: a forced-device child on the CPU backend,
+    in-process over the first ``args.devices`` chips elsewhere."""
     import time
 
     import jax
     import numpy as np
 
-    from benchmarks.common import RATE_SCALE
+    from benchmarks.common import RATE_SCALE, arm_devices
     from repro.compat import make_mesh
     from repro.core.environment import EnvironmentParams, build_task_queue
     from repro.core.flexai import (FlexAIAgent, FlexAIConfig,
@@ -42,8 +45,7 @@ def _child_main(args) -> None:
                                   pad_task_arrays, stack_task_arrays,
                                   tasks_to_arrays)
 
-    n_dev = len(jax.devices())
-    assert n_dev == args.devices, (n_dev, args.devices)
+    n_dev = arm_devices(args.devices)
 
     # a few unique routes, tiled out to the lane count (same math, cheap
     # host-side queue generation)
@@ -116,8 +118,8 @@ def _child_main(args) -> None:
 
 def _spawn(devices: int, lanes: int, tasks: int, iters: int,
            unique_routes: int) -> dict:
-    from benchmarks.common import spawn_forced_device_child
-    return spawn_forced_device_child(
+    from benchmarks.common import run_device_arm
+    return run_device_arm(
         "sharded_engine", devices,
         ["--lanes", lanes, "--tasks", tasks, "--iters", iters,
          "--unique-routes", unique_routes],

@@ -211,7 +211,7 @@ def _td_kernel_arm(tasks: int, episodes: int, reps: int = 3) -> dict:
     lowered to plain XLA ops), so the ratio here is NOT a hardware kernel
     claim in either direction — it is reported, never gated.  The
     compiled ratio lives in ``BENCH_kernels.json``'s compiled leg, which
-    only runs on a TPU/GPU host under ``REPRO_KERNEL_COMPILED=1``."""
+    runs only off the CPU backend."""
     import jax
     import numpy as np
 
@@ -281,14 +281,13 @@ def _child_main(args) -> None:
     import jax
     import numpy as np
 
-    from benchmarks.common import platform
+    from benchmarks.common import arm_devices, platform
     from repro.compat import make_mesh
     from repro.core.flexai import dp_train_init, make_dp_train_fn
     from repro.core.platform_jax import spec_from_platform
     from repro.core.tasks import stack_task_arrays, tasks_to_arrays
 
-    n_dev = len(jax.devices())
-    assert n_dev == args.devices, (n_dev, args.devices)
+    n_dev = arm_devices(args.devices)
     cfg = _dp_cfg()
     plat = platform()
     spec = spec_from_platform(plat)
@@ -386,8 +385,8 @@ def _child_main(args) -> None:
 
 
 def _spawn(devices: int, lanes: int, tasks: int, iters: int) -> dict:
-    from benchmarks.common import spawn_forced_device_child
-    return spawn_forced_device_child(
+    from benchmarks.common import run_device_arm
+    return run_device_arm(
         "training_throughput", devices,
         ["--dp-lanes", lanes, "--tasks", tasks, "--iters", iters],
         RESULT_TAG)

@@ -2,7 +2,10 @@
 camera task queue -> FlexAI scheduling -> heterogeneous virtual-accelerator
 pools actually executing the perception CNNs with batched requests.
 
-    PYTHONPATH=src python examples/serve_driving_pipeline.py
+    XLA_FLAGS=--xla_force_host_platform_device_count=3 \
+        PYTHONPATH=src python examples/serve_driving_pipeline.py
+
+(each of the three pools owns a device of its own.)
 
 This is the TPU adaptation of Fig 5's data path: cameras -> per-camera
 buffers -> RL scheduling strategy -> per-accelerator execution, with the

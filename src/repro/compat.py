@@ -1,139 +1,78 @@
-"""Version-adaptive JAX compatibility seam.
+"""The repo's few seams onto the installed JAX (0.9).
 
-The repo targets the newest public JAX API surface (``jax.shard_map``,
-``jax.make_mesh(axis_types=...)``, ``pltpu.CompilerParams``); CI and the
-baked container run jax 0.4.37, where those names live elsewhere or do not
-exist yet.  Every version-sensitive symbol is resolved HERE, once, at import
-time — call sites import from ``repro.compat`` and never probe ``jax``
-themselves.
+Everything else calls JAX by its own names (``jax.shard_map``,
+``jax.sharding.AbstractMesh``, ``pltpu.CompilerParams``).  What lives here
+holds a decision more than one module depends on:
 
-Shimmed surface (see DESIGN.md "Compat-shim policy" for the drop rules):
-
-=====================  ====================================================
-export                 resolves to
-=====================  ====================================================
-``shard_map``          ``jax.shard_map`` (>= 0.6) else
-                       ``jax.experimental.shard_map.shard_map``
-``make_mesh``          ``jax.make_mesh`` with ``axis_types`` forwarded when
-                       supported, silently dropped otherwise
-``abstract_mesh``      ``jax.sharding.AbstractMesh`` under both calling
-                       conventions: ``(shape, names)`` (new) vs the 0.4.x
-                       ``((name, size), ...)`` shape-tuple
-``default_axis_types`` ``(jax.sharding.AxisType.Auto,) * n`` when
-                       ``AxisType`` exists, else ``None``
-``CompilerParams``     ``pltpu.CompilerParams`` (>= 0.6) else
-                       ``pltpu.TPUCompilerParams``
-``pallas_interpret_default``  True off-accelerator (Pallas kernels fall
-                       back to interpret mode so CPU CI executes the
-                       kernel bodies); ``REPRO_KERNEL_COMPILED=1`` also
-                       compiles on GPU, ``=0`` forces interpret (debug)
-=====================  ====================================================
+* :func:`make_mesh` — every mesh the repo builds has ``Auto`` axes
+  (``jax.make_mesh`` defaults to ``Explicit``);
+* :func:`vary_like` — ``shard_map``'s varying-manual-axes typing for
+  carries the engines build from replicated values;
+* :func:`pallas_interpret_default` — Pallas runs interpreted on the CPU
+  backend and compiled everywhere else;
+* :func:`enable_compile_cache` — the persistent compilation cache every
+  entry point shares.
 """
 from __future__ import annotations
 
-import inspect
+import os
+import pathlib
 
 import jax
 
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):                     # jax >= 0.6
-    shard_map = jax.shard_map
-else:                                             # jax 0.4.x / 0.5.x
-    from jax.experimental.shard_map import shard_map  # noqa: F401
+# <repo>/.jax_cache: a fixed path inside the checkout (the cache key
+# includes it, so a path that moved between runs would never hit)
+COMPILE_CACHE_DIR = pathlib.Path(__file__).resolve().parents[2] / ".jax_cache"
 
 
-# ---------------------------------------------------------------------------
-# mesh construction
-# ---------------------------------------------------------------------------
-
-HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-_MAKE_MESH_TAKES_AXIS_TYPES = (
-    "axis_types" in inspect.signature(jax.make_mesh).parameters)
-
-
-def default_axis_types(n_axes: int):
-    """``(AxisType.Auto,) * n`` on new JAX, ``None`` where the enum does not
-    exist (0.4.x meshes are implicitly Auto)."""
-    if HAS_AXIS_TYPE:
-        return (jax.sharding.AxisType.Auto,) * n_axes
-    return None
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``."""
+    names = tuple(axis_names)
+    return jax.make_mesh(tuple(axis_shapes), names,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+                         devices=devices)
 
 
-def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None):
-    """``jax.make_mesh`` that tolerates pre-``axis_types`` JAX.
+def vary_like(tree, *refs):
+    """Mark every leaf of ``tree`` as varying over the manual mesh axes
+    that any of ``refs`` varies over.
 
-    ``axis_types=None`` asks for the default (Auto on every axis); on old
-    JAX the kwarg is dropped entirely, which means the same thing.
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if _MAKE_MESH_TAKES_AXIS_TYPES:
-        if axis_types is None:
-            axis_types = default_axis_types(len(tuple(axis_names)))
-        kwargs["axis_types"] = axis_types
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    Inside ``shard_map`` a ``lax.scan`` carry or ``lax.cond`` operand that
+    the body builds from replicated values (a fresh ``PlatformState``, a
+    constant) is typed invariant, while the value the body returns for it
+    varies with the sharded inputs; JAX rejects the mismatch.  Casting the
+    initial value to the refs' axes fixes the type without moving data.
+    Outside ``shard_map`` nothing varies and this is the identity, so the
+    single-device and sharded engines share one body."""
+    want = frozenset().union(*(jax.typeof(r).vma for r in refs))
 
+    def cast(x):
+        missing = tuple(sorted(want - jax.typeof(x).vma))
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
 
-def abstract_mesh(axis_shapes, axis_names):
-    """``jax.sharding.AbstractMesh`` under either calling convention.
-
-    New JAX: ``AbstractMesh(axis_shapes, axis_names)``.  0.4.x:
-    ``AbstractMesh(shape_tuple)`` with ``((name, size), ...)`` pairs.
-    """
-    AbstractMesh = jax.sharding.AbstractMesh
-    params = list(inspect.signature(AbstractMesh.__init__).parameters)
-    if "axis_names" in params or "axis_name" in params:
-        return AbstractMesh(tuple(axis_shapes), tuple(axis_names))
-    return AbstractMesh(tuple(zip(axis_names, axis_shapes)))
+    return jax.tree_util.tree_map(cast, tree) if want else tree
 
 
-# ---------------------------------------------------------------------------
-# Pallas TPU
-# ---------------------------------------------------------------------------
-
-def __getattr__(name):
-    # ``CompilerParams`` resolves lazily so non-kernel consumers of this
-    # module (sharding, serving, launch) never pay the Pallas/Mosaic
-    # import at startup.  jax >= 0.6 renamed TPUCompilerParams ->
-    # CompilerParams; accept either.
-    if name == "CompilerParams":
-        from jax.experimental.pallas import tpu as _pltpu
-        return getattr(_pltpu, "CompilerParams", None) \
-            or _pltpu.TPUCompilerParams
-    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
-
-
-def _interpret_for(platform: str, compiled_env: str | None) -> bool:
-    """Pure decision core of :func:`pallas_interpret_default` (split out so
-    the protocol tests can exercise every platform/env combination on a
-    CPU-only host).
-
-    * ``REPRO_KERNEL_COMPILED=0`` forces interpret everywhere (debug).
-    * TPU compiles by default (Mosaic is the native path).
-    * ``REPRO_KERNEL_COMPILED=1`` additionally compiles on GPU (Triton
-      lowering) — the hardware-run protocol of ``repro.kernels.protocol``.
-    * CPU has no Pallas compiler: always interpret, even when compiled
-      mode is requested — the benchmark/CI layer reports that skip
-      explicitly rather than silently greening.
-    """
-    if compiled_env == "0":
-        return True
-    if platform == "tpu":
-        return False
-    if compiled_env == "1" and platform == "gpu":
-        return False
-    return True
+def _interpret_for(platform: str) -> bool:
+    """Pallas runs interpreted exactly when the backend is the CPU, which
+    has no Pallas compiler; any other backend compiles the kernel."""
+    return platform == "cpu"
 
 
 def pallas_interpret_default() -> bool:
-    """Pallas kernels compile (Mosaic/Triton) only on TPU — or on GPU when
-    ``REPRO_KERNEL_COMPILED=1`` requests the compiled hardware run;
-    everywhere else default to interpret mode so the same call sites run
-    under CPU CI."""
-    import os
-    return _interpret_for(jax.devices()[0].platform,
-                          os.environ.get("REPRO_KERNEL_COMPILED"))
+    """Default for every kernel's ``interpret=`` argument."""
+    return _interpret_for(jax.devices()[0].platform)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache for an entry point and
+    return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here; otherwise the cache lives in
+    :data:`COMPILE_CACHE_DIR`.  Tests do not call this."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
+    return str(COMPILE_CACHE_DIR)

@@ -29,6 +29,12 @@ class DQNParams(NamedTuple):
 
 HIDDEN = (256, 64)
 
+# f32 matmuls at full precision on every backend.  At the default a TPU
+# runs [M >= 2, D] dots as one bf16 MXU pass but strength-reduces a
+# one-row [1, D] dot to an exact f32 VPU reduction, so the greedy argmax
+# would depend on how many wave lanes share a device.
+PRECISION = jax.lax.Precision.HIGHEST
+
 
 def init_qnet(key, state_dim: int, n_actions: int) -> DQNParams:
     k1, k2, k3 = jax.random.split(key, 3)
@@ -48,9 +54,9 @@ def init_qnet(key, state_dim: int, n_actions: int) -> DQNParams:
 
 def qnet_apply(p: DQNParams, state: jax.Array) -> jax.Array:
     """state [..., state_dim] -> Q values [..., n_actions]."""
-    h = jax.nn.relu(state @ p.w1 + p.b1)
-    h = jax.nn.relu(h @ p.w2 + p.b2)
-    return h @ p.w3 + p.b3
+    h = jax.nn.relu(jnp.matmul(state, p.w1, precision=PRECISION) + p.b1)
+    h = jax.nn.relu(jnp.matmul(h, p.w2, precision=PRECISION) + p.b2)
+    return jnp.matmul(h, p.w3, precision=PRECISION) + p.b3
 
 
 class AdamState(NamedTuple):

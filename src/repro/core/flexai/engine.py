@@ -29,6 +29,7 @@ import jax.flatten_util  # noqa: F401  (jax.flatten_util.ravel_pytree)
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compat import vary_like
 from repro.core.flexai.dqn import (AdamState, DQNParams, _adam_init,
                                    adam_apply, dqn_td_grads, dqn_td_update,
                                    init_qnet, qnet_apply)
@@ -72,6 +73,7 @@ def _schedule_run(spec: PlatformSpec, backlog_scale: float):
         t = tasks.arrival.shape[0]
         trace = (jnp.ones((t, spec.n), jnp.float32) if health is None
                  else jnp.asarray(health, jnp.float32))
+        init, trace = vary_like((init, trace), tasks.arrival)
         final, recs = jax.lax.scan(functools.partial(body, params),
                                    init, (tasks, trace))
         return final, recs
@@ -99,6 +101,7 @@ def _schedule_run_masked(spec: PlatformSpec, backlog_scale: float):
 
     def run(params, tasks: TaskArrays, state0=None, alive=None):
         init = platform_init(spec.n) if state0 is None else state0
+        init = vary_like(init, tasks.arrival)
         mask = jnp.ones((spec.n,), bool) if alive is None else alive
         final, recs = jax.lax.scan(
             functools.partial(body, params, mask), init, tasks)
@@ -146,11 +149,9 @@ def make_sharded_schedule_fn(spec: PlatformSpec, mesh,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     run = jax.vmap(_schedule_run(spec, backlog_scale), in_axes=(None, 0))
-    sharded = shard_map(run, mesh=mesh, in_specs=(P(), P(axis)),
-                        out_specs=P(axis))
+    sharded = jax.shard_map(run, mesh=mesh, in_specs=(P(), P(axis)),
+                            out_specs=P(axis))
     return jax.jit(sharded)
 
 
@@ -255,7 +256,7 @@ def _train_run(spec: PlatformSpec, cfg, td_kernel: bool = False):
 
         def skip(_):
             return (ts.eval_p, ts.targ_p, ts.opt, ts.updates,
-                    jnp.float32(0.0))
+                    vary_like(jnp.float32(0.0), reward))
 
         eval_p, targ_p, opt, updates, loss = jax.lax.cond(
             do_update, upd, skip, None)
@@ -279,7 +280,8 @@ def _train_run(spec: PlatformSpec, cfg, td_kernel: bool = False):
         done = jnp.arange(t) == tasks.valid.sum() - 1
         trace = (jnp.ones((t, spec.n), jnp.float32) if health is None
                  else jnp.asarray(health, jnp.float32))
-        plat0 = platform_init(spec.n)
+        plat0, trace = vary_like((platform_init(spec.n), trace),
+                                 tasks.arrival)
         sv0 = state_vector(spec, feat, cfg.backlog_scale, plat0,
                            jax.tree_util.tree_map(lambda a: a[0], tasks))
         (ts_f, plat_f, _), (recs, losses, upd_mask) = jax.lax.scan(
@@ -299,7 +301,7 @@ def make_train_fn(spec: PlatformSpec, cfg, batched: bool = False,
     records, losses, update_mask)``.  ``batched=True`` vmaps over lanes:
     stacked TrainState (independent seeds) x stacked routes.
     ``td_kernel=True`` runs the TD update through the Pallas fused kernel
-    (interpret-mode off-accelerator; see ``repro.kernels.protocol``).
+    (interpreted on the CPU backend; see ``repro.kernels.protocol``).
     """
     # note: no buffer donation — at init eval_p and targ_p alias the same
     # arrays, and donating an aliased pytree is an XLA error
@@ -327,12 +329,10 @@ def make_sharded_train_fn(spec: PlatformSpec, cfg, mesh,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     run = jax.vmap(_train_run(spec, cfg, td_kernel=td_kernel),
                    in_axes=(0, 0))
-    sharded = shard_map(run, mesh=mesh, in_specs=(P(axis), P(axis)),
-                        out_specs=P(axis))
+    sharded = jax.shard_map(run, mesh=mesh, in_specs=(P(axis), P(axis)),
+                            out_specs=P(axis))
     return jax.jit(sharded)
 
 
@@ -451,8 +451,13 @@ def _dp_train_run(spec: PlatformSpec, cfg, lanes: int, axis=None,
             batches = jax.vmap(
                 lambda b, k: device_replay_sample(b, k, cfg.batch_size)
             )(replay, lane_keys(k_smp))
+            # differentiate a shard-local (varying) view of the shared
+            # weights: the gradient of the replicated ones would already
+            # be psum'd across shards inside the backward pass, ahead of
+            # the explicit pmean below
+            eval_p, targ_p = vary_like((ts.eval_p, ts.targ_p), replay.size)
             return jax.vmap(
-                lambda b: td_grads(ts.eval_p, ts.targ_p, b,
+                lambda b: td_grads(eval_p, targ_p, b,
                                    gamma=cfg.gamma))(batches)
 
         # cadence = update_every-boundary CROSSING, not an exact-multiple
@@ -537,7 +542,9 @@ def _dp_train_run(spec: PlatformSpec, cfg, lanes: int, axis=None,
         t = tasks.arrival.shape[1]
         done = jnp.arange(t)[None, :] == \
             tasks.valid.sum(axis=1, keepdims=True) - 1
-        plats0 = jax.vmap(lambda _: platform_init(spec.n))(jnp.arange(lanes))
+        plats0 = vary_like(
+            jax.vmap(lambda _: platform_init(spec.n))(jnp.arange(lanes)),
+            tasks.arrival)
         svs0 = jax.vmap(
             lambda p, trow: state_vector(spec, feat, cfg.backlog_scale,
                                          p, trow)
@@ -579,8 +586,6 @@ def make_dp_train_fn(spec: PlatformSpec, cfg, lanes: int, mesh=None,
                                      td_kernel=td_kernel))
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     if lanes < 1 or lanes % mesh.size:
         raise ValueError(f"lanes={lanes} must be a positive multiple of "
                          f"the mesh size {mesh.size}")
@@ -590,8 +595,8 @@ def make_dp_train_fn(spec: PlatformSpec, cfg, lanes: int, mesh=None,
                         td_kernel=td_kernel)
     ts_specs = TrainState(eval_p=P(), targ_p=P(), opt=P(), replay=P(axis),
                           env_steps=P(), updates=P(), key=P())
-    sharded = shard_map(run, mesh=mesh, in_specs=(ts_specs, P(axis)),
-                        out_specs=(ts_specs, P(axis), P(axis), P(), P()))
+    sharded = jax.shard_map(run, mesh=mesh, in_specs=(ts_specs, P(axis)),
+                            out_specs=(ts_specs, P(axis), P(axis), P(), P()))
     return jax.jit(sharded)
 
 
@@ -619,9 +624,8 @@ class ScanFlexAI:
     its ``pmean`` + shared ``adam_apply``.  Default off — the flag is a
     trace-time Python branch, so the kernel compiles out entirely and
     the default trainer stays bit-identical to the pre-kernel engine.
-    Off-accelerator the kernel runs in Pallas interpret mode (slower on
-    CPU — honest numbers in BENCH_kernels.json); set
-    ``REPRO_KERNEL_COMPILED=1`` on a TPU/GPU host to run it compiled.
+    On the CPU backend the kernel runs in Pallas interpret mode (slower
+    there — honest numbers in BENCH_kernels.json); on a TPU it compiles.
     """
 
     def __init__(self, platform, cfg, lanes: int = 1, mesh=None,

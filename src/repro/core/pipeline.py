@@ -50,6 +50,7 @@ import jax.flatten_util  # noqa: F401  (jax.flatten_util.ravel_pytree)
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compat import vary_like
 from repro.core.flexai.dqn import (DQNParams, adam_apply, dqn_td_grads,
                                    dqn_td_update, qnet_apply)
 from repro.core.flexai.engine import TrainState, dp_train_init, train_init
@@ -534,8 +535,6 @@ def make_sharded_pipeline_fn(spec: PlatformSpec, plan: StagePlan, mesh,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     S = int(plan.stage_exec.shape[0])
     if mesh.shape[stage_axis] != S:
         raise ValueError(
@@ -579,8 +578,10 @@ def make_sharded_pipeline_fn(spec: PlatformSpec, plan: StagePlan, mesh,
 
         states0 = jax.vmap(lambda _: platform_init(spec.n))(jnp.arange(R))
         z = jnp.zeros((R,), jnp.float32)
+        # each stage shard's carry varies over both mesh axes
         (statesF, ringF, _), recs = jax.lax.scan(
-            col, (states0, z, z), jnp.arange(C))
+            col, vary_like((states0, z, z), tasks.arrival, my_s),
+            jnp.arange(C))
         recs = jax.tree_util.tree_map(
             lambda a: jnp.moveaxis(a, 0, 1), recs)          # [R, C]
         cols = my_s + jnp.arange(T)                          # own diagonal
@@ -589,7 +590,7 @@ def make_sharded_pipeline_fn(spec: PlatformSpec, plan: StagePlan, mesh,
         return (jax.tree_util.tree_map(lead, statesF), ringF[None],
                 jax.tree_util.tree_map(lead, recs))
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         block, mesh=mesh, in_specs=(P(), P(route_axis)),
         out_specs=(P(stage_axis, route_axis), P(stage_axis, route_axis),
                    P(stage_axis, route_axis)))
@@ -705,7 +706,7 @@ def _pipeline_train_run(spec: PlatformSpec, plan: StagePlan, cfg):
 
         def skip(_):
             return (ts.eval_p, ts.targ_p, ts.opt, ts.updates,
-                    jnp.float32(0.0))
+                    vary_like(jnp.float32(0.0), reward))
 
         eval_p, targ_p, opt, updates, loss = jax.lax.cond(
             do_update, upd, skip, None)
@@ -720,8 +721,9 @@ def _pipeline_train_run(spec: PlatformSpec, plan: StagePlan, cfg):
         nv, done = _next_valid_flat(rows.valid)
         nrows = jax.tree_util.tree_map(lambda a: a[nv], rows)
         ns = s_seq[nv]
-        plat0 = platform_init(spec.n)
-        ring0 = jnp.zeros((S,), jnp.float32)
+        plat0, ring0 = vary_like(
+            (platform_init(spec.n), jnp.zeros((S,), jnp.float32)),
+            tasks.arrival)
         _, sv0 = _stage_obs(
             spec, plan, feat, cfg.backlog_scale, plat0, ring0,
             jax.tree_util.tree_map(lambda a: a[0], rows), s_seq[0])
@@ -751,11 +753,9 @@ def make_sharded_pipeline_train_fn(spec: PlatformSpec, plan: StagePlan,
     ``make_sharded_train_fn``)."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     run = jax.vmap(_pipeline_train_run(spec, plan, cfg), in_axes=(0, 0))
-    sharded = shard_map(run, mesh=mesh, in_specs=(P(axis), P(axis)),
-                        out_specs=P(axis))
+    sharded = jax.shard_map(run, mesh=mesh, in_specs=(P(axis), P(axis)),
+                            out_specs=P(axis))
     return jax.jit(sharded)
 
 
@@ -832,8 +832,10 @@ def _pipeline_dp_train_run(spec: PlatformSpec, plan: StagePlan, cfg,
             batches = jax.vmap(
                 lambda b, k: device_replay_sample(b, k, cfg.batch_size)
             )(replay, lane_keys(k_smp))
+            # shard-local view of the shared weights (see _dp_train_run)
+            eval_p, targ_p = vary_like((ts.eval_p, ts.targ_p), replay.size)
             losses, grads = jax.vmap(
-                lambda b: dqn_td_grads(ts.eval_p, ts.targ_p, b,
+                lambda b: dqn_td_grads(eval_p, targ_p, b,
                                        gamma=cfg.gamma))(batches)
             flat, unravel = jax.flatten_util.ravel_pytree(
                 (losses.mean(),
@@ -871,8 +873,9 @@ def _pipeline_dp_train_run(spec: PlatformSpec, plan: StagePlan, cfg,
         nrows = jax.tree_util.tree_map(
             lambda a: jnp.take_along_axis(a, nv, axis=1), rows)
         ns = s_seq[nv]
-        plats0 = jax.vmap(lambda _: platform_init(spec.n))(jnp.arange(lanes))
-        rings0 = jnp.zeros((lanes, S), jnp.float32)
+        plats0, rings0 = vary_like(
+            (jax.vmap(lambda _: platform_init(spec.n))(jnp.arange(lanes)),
+             jnp.zeros((lanes, S), jnp.float32)), tasks.arrival)
         svs0 = jax.vmap(
             lambda p, r, rw: _stage_obs(spec, plan, feat, cfg.backlog_scale,
                                         p, r, rw, s_seq[0])[1]
@@ -899,8 +902,6 @@ def make_pipeline_dp_train_fn(spec: PlatformSpec, plan: StagePlan, cfg,
         return jax.jit(_pipeline_dp_train_run(spec, plan, cfg, lanes))
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     if lanes < 1 or lanes % mesh.size:
         raise ValueError(f"lanes={lanes} must be a positive multiple of "
                          f"the mesh size {mesh.size}")
@@ -908,8 +909,8 @@ def make_pipeline_dp_train_fn(spec: PlatformSpec, plan: StagePlan, cfg,
                                  axis=axis, n_shards=mesh.size)
     ts_specs = TrainState(eval_p=P(), targ_p=P(), opt=P(), replay=P(axis),
                           env_steps=P(), updates=P(), key=P())
-    sharded = shard_map(run, mesh=mesh, in_specs=(ts_specs, P(axis)),
-                        out_specs=(ts_specs, P(axis), P(axis), P(), P()))
+    sharded = jax.shard_map(run, mesh=mesh, in_specs=(ts_specs, P(axis)),
+                            out_specs=(ts_specs, P(axis), P(axis), P(), P()))
     return jax.jit(sharded)
 
 

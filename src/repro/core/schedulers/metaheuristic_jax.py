@@ -38,6 +38,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.compat import vary_like
 from repro.core.faults import window_health
 from repro.core.platform_jax import (PlatformSpec, PlatformState,
                                      health_capacity, platform_init,
@@ -286,6 +287,7 @@ def _route_run(spec: PlatformSpec, cfg, search):
         trace = (jnp.ones((tasks.arrival.shape[0], spec.n), jnp.float32)
                  if health is None else jnp.asarray(health, jnp.float32))
         init = platform_init(spec.n) if state0 is None else state0
+        init, trace = vary_like((init, trace), tasks.arrival)
         (state, _), recs = jax.lax.scan(win_body, (init, key),
                                         (win, window_health(trace, window)))
         recs = jax.tree_util.tree_map(
@@ -325,13 +327,11 @@ def make_sharded_metaheuristic_fn(spec: PlatformSpec, name: str, mesh,
     Window searches are route-local, so no collectives are involved."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
-
     search, cfg_cls = _WINDOW_SEARCHES[name]
     cfg = cfg_cls() if cfg is None else cfg
     run = jax.vmap(_route_run(spec, cfg, search), in_axes=(0, 0))
-    sharded = shard_map(run, mesh=mesh, in_specs=(P(axis), P(axis)),
-                        out_specs=P(axis))
+    sharded = jax.shard_map(run, mesh=mesh, in_specs=(P(axis), P(axis)),
+                            out_specs=P(axis))
     return jax.jit(sharded)
 
 

@@ -130,11 +130,19 @@ class VirtualPlatform(H.HMAIPlatform):
     def __init__(self, pool_specs=DEFAULT_POOLS, seed: int = 0,
                  run_real: bool = True):
         devices = jax.devices()
+        wanted = sum(ps.n_devices for ps in pool_specs)
+        if wanted > len(devices):
+            # pools own disjoint device groups; stacking them on one
+            # device would advertise capacity that is not there
+            raise ValueError(
+                f"pools ask for {wanted} devices, {len(devices)} available "
+                f"(on the CPU backend, force more with XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={wanted})")
         self.pools: list[VirtualAcceleratorPool] = []
         key = jax.random.PRNGKey(seed)
         di = 0
         for i, ps in enumerate(pool_specs):
-            devs = devices[di: di + ps.n_devices] or devices[:1]
+            devs = devices[di: di + ps.n_devices]
             di += ps.n_devices
             pool = VirtualAcceleratorPool(ps, devs, jax.random.fold_in(key, i))
             pool.calibrate()

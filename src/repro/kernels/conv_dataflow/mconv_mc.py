@@ -1,6 +1,6 @@
 """MconvMC — Mconv-MP-CR archetype (Origami) as a Pallas TPU kernel.
 
-Taxonomy mapping (DESIGN.md §3):
+Taxonomy mapping (DESIGN.md "TPU taxonomy adaptation"):
   * Mconv: each BasicUnit iteration processes MULTIPLE 2D convolutions —
     a [Tc (in-channel) x Tm (out-channel)] tile of channel pairs at once,
     as an im2col matrix multiplication on the MXU (Origami's matrix unit;
@@ -21,63 +21,66 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
+from repro.kernels.conv_dataflow.tiling import (LANE, VMEM_LIMIT_BYTES,
+                                                accumulate_plane, pad_plane)
 
 
-def _kernel(x_ref, w_ref, o_ref, acc_ref, *, kh: int, kw: int):
+def _kernel(x_ref, w_ref, o_ref, acc_ref, *, kh: int, kw: int, wo: int):
     ci_step = pl.program_id(2)
-    n_ci = pl.num_programs(2)
 
     @pl.when(ci_step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    ho, wo = o_ref.shape[0], o_ref.shape[1]
-    tc = x_ref.shape[-1]
-    tm = o_ref.shape[-1]
     # im2col GEMM: each tap contributes [Ho*Wo, Tc] @ [Tc, Tm] on the MXU
-    acc = acc_ref[...].reshape(ho * wo, tm)
-    for di in range(kh):
-        for dj in range(kw):
-            patch = x_ref[pl.ds(di, ho), pl.ds(dj, wo), :]   # [Ho, Wo, Tc]
-            mat = patch.reshape(ho * wo, tc)
-            acc += jax.lax.dot(
-                mat.astype(jnp.float32),
-                w_ref[di, dj, :, :].astype(jnp.float32),
-                preferred_element_type=jnp.float32)
-    acc_ref[...] = acc.reshape(ho, wo, tm)
+    accumulate_plane(x_ref, w_ref, acc_ref, kh, kw, wo)
 
-    @pl.when(ci_step == n_ci - 1)
+    @pl.when(ci_step == pl.num_programs(2) - 1)
     def _flush():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def mconv_mc(x: jax.Array, w: jax.Array, *, cout_tile: int = 128,
-             cin_tile: int = 32, interpret: bool = False) -> jax.Array:
-    """x [N,H,W,Cin], w [KH,KW,Cin,Cout] -> [N,Ho,Wo,Cout] (stride 1, VALID)."""
-    n, h, wd, cin = x.shape
+def mconv_mc(x: jax.Array, w: jax.Array, *, cout_tile: int = LANE,
+             cin_tile: int = LANE, interpret: bool = False) -> jax.Array:
+    """x [N,H,W,Cin], w [KH,KW,Cin,Cout] -> [N,Ho,Wo,Cout] (stride 1, VALID).
+
+    Compiled for a TPU, each tile must be a multiple of 128 or cover its
+    whole channel axis (the lane dim of its block)."""
+    n, _, _, cin = x.shape
     kh, kw, _, cout = w.shape
-    ho, wo = h - kh + 1, wd - kw + 1
+    # both channel grids cover whole tiles: a channel count a tile does not
+    # divide zero-pads to the next tile boundary (zero ifmap channels add
+    # nothing to a psum; zero filter columns give output channels that are
+    # sliced off below)
     cout_tile = min(cout_tile, cout)
     cin_tile = min(cin_tile, cin)
-    assert cout % cout_tile == 0 and cin % cin_tile == 0
-    grid = (n, cout // cout_tile, cin // cin_tile)
+    n_co, n_ci = pl.cdiv(cout, cout_tile), pl.cdiv(cin, cin_tile)
+    cout_pad, cin_pad = n_co * cout_tile, n_ci * cin_tile
+    if (cin_pad, cout_pad) != (cin, cout):
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, cin_pad - cin)))
+        w = jnp.pad(w, ((0, 0), (0, 0), (0, cin_pad - cin),
+                        (0, cout_pad - cout)))
+    x, ho, wo, ho_pad, wo_pad = pad_plane(x, kh, kw)
+    h, wd = x.shape[1], x.shape[2]
 
-    return pl.pallas_call(
-        functools.partial(_kernel, kh=kh, kw=kw),
-        grid=grid,
+    out = pl.pallas_call(
+        functools.partial(_kernel, kh=kh, kw=kw, wo=wo_pad),
+        grid=(n, n_co, n_ci),
         in_specs=[
             pl.BlockSpec((None, h, wd, cin_tile),
                          lambda b, co, ci: (b, 0, 0, ci)),
             pl.BlockSpec((kh, kw, cin_tile, cout_tile),
                          lambda b, co, ci: (0, 0, ci, co)),
         ],
-        out_specs=pl.BlockSpec((None, ho, wo, cout_tile),
-                               lambda b, co, ci: (b, 0, 0, co)),
-        out_shape=jax.ShapeDtypeStruct((n, ho, wo, cout), x.dtype),
-        scratch_shapes=[pltpu.VMEM((ho, wo, cout_tile), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        out_specs=pl.BlockSpec((None, ho_pad * wo_pad, cout_tile),
+                               lambda b, co, ci: (b, 0, co)),
+        out_shape=jax.ShapeDtypeStruct((n, ho_pad * wo_pad, cout_pad),
+                                       x.dtype),
+        scratch_shapes=[pltpu.VMEM((ho_pad * wo_pad, cout_tile), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="mconv_mc",
     )(x, w)
+    return out.reshape(n, ho_pad, wo_pad, cout_pad)[:, :ho, :wo, :cout]

@@ -1,8 +1,12 @@
 """jit'd wrappers for the three conv-dataflow kernels.
 
 ``conv2d(x, w, dataflow=...)`` handles SAME/VALID padding and stride by
-pre-padding / post-slicing around the stride-1 VALID kernels, picks
-hardware-aligned tile sizes, and falls back to interpret mode off-TPU.
+pre-padding / post-slicing around the stride-1 VALID kernels, and runs the
+kernels interpreted on the CPU backend only.  Channel axes tile at 128
+lanes (``tiling.LANE``) and the kernels zero-pad a channel count that 128
+does not divide, so a layer of more than 128 input channels takes several
+sequential steps of SconvOD's and MconvMC's channel grids, and one of more
+than 128 output channels several MconvMC channel-pair tiles.
 """
 from __future__ import annotations
 
@@ -20,17 +24,6 @@ from repro.kernels.conv_dataflow.sconv_od import sconv_od
 DATAFLOWS = ("SconvOD", "SconvIC", "MconvMC")
 
 
-def _tile(n: int, target: int) -> int:
-    # largest divisor <= target: still required by mconv_mc, whose grid
-    # must divide the channel dims exactly.  sconv_ic / sconv_od pad to
-    # the requested tile internally (masked/zero tail blocks), so they
-    # take `target` directly and prime dims no longer degrade the grid.
-    t = min(target, n)
-    while n % t:
-        t -= 1
-    return max(t, 1)
-
-
 @functools.partial(jax.jit, static_argnames=("dataflow", "stride", "padding",
                                              "interpret"))
 def conv2d(x: jax.Array, w: jax.Array, *, dataflow: str = "MconvMC",
@@ -42,18 +35,17 @@ def conv2d(x: jax.Array, w: jax.Array, *, dataflow: str = "MconvMC",
     """
     if interpret is None:
         interpret = pallas_interpret_default()
-    kh, kw, cin, cout = w.shape
+    kh, kw = w.shape[:2]
     if padding == "SAME":
         ph, pw = (kh - 1) // 2, (kw - 1) // 2
         x = jnp.pad(x, ((0, 0), (ph, kh - 1 - ph), (pw, kw - 1 - pw), (0, 0)))
 
     if dataflow == "SconvOD":
-        out = sconv_od(x, w, cin_tile=8, interpret=interpret)
+        out = sconv_od(x, w, interpret=interpret)
     elif dataflow == "SconvIC":
-        out = sconv_ic(x, w, row_tile=8, interpret=interpret)
+        out = sconv_ic(x, w, interpret=interpret)
     elif dataflow == "MconvMC":
-        out = mconv_mc(x, w, cout_tile=_tile(cout, 128),
-                       cin_tile=_tile(cin, 32), interpret=interpret)
+        out = mconv_mc(x, w, interpret=interpret)
     elif dataflow == "ref":
         out = conv2d_ref(x, w)
     else:

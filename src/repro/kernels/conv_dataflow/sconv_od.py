@@ -1,8 +1,8 @@
 """SconvOD — Sconv-OP-DR archetype (NeuFlow) as a Pallas TPU kernel.
 
-Taxonomy mapping (DESIGN.md §3):
-  * Sconv: one whole 2D convolution (one input channel's contribution to
-    all output pixels) per BasicUnit iteration.
+Taxonomy mapping (DESIGN.md "TPU taxonomy adaptation"):
+  * Sconv: whole 2D convolutions — every grid step covers the full output
+    plane for one slice of input channels.
   * OP (ofmaps propagate): partial sums accumulate ACROSS sequential grid
     steps over input channels — the VMEM accumulator plays the role of the
     PE->PE psum FIFO chain.
@@ -10,9 +10,9 @@ Taxonomy mapping (DESIGN.md §3):
     slice stay resident (weight-stationary) while the ifmap streams —
     per-PE weight registers become the resident VMEM filter block.
 
-Compute style: tap-by-tap shifted multiply-accumulate over the output
-plane (VPU lanes = the PE array), NOT an MXU matmul — matching the
-paper's "1 MAC per PE, no on-chip buffer" row of Table 10.
+Each tap contracts the channel slice on the MXU,
+``[Ho*Wo, Cin_tile] @ [Cin_tile, Cout]``; what separates this kernel from
+MconvMC is its grid and what stays resident, not the MAC unit.
 
 Grid: (N, Cin_tiles) with the channel dim sequential ("arbitrary").
 """
@@ -25,67 +25,61 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
+from repro.kernels.conv_dataflow.tiling import (LANE, VMEM_LIMIT_BYTES,
+                                                accumulate_plane, pad_plane)
 
 
-def _kernel(x_ref, w_ref, o_ref, acc_ref, *, kh: int, kw: int, cin_tile: int):
+def _kernel(x_ref, w_ref, o_ref, acc_ref, *, kh: int, kw: int, wo: int):
     ci_step = pl.program_id(1)
-    n_ci = pl.num_programs(1)
 
     @pl.when(ci_step == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    ho, wo = o_ref.shape[0], o_ref.shape[1]
-    acc = acc_ref[...]
-    # whole-2D-conv per channel: shifted planes x resident taps (VPU MACs)
-    for ci in range(cin_tile):
-        for di in range(kh):
-            for dj in range(kw):
-                plane = x_ref[di: di + ho, dj: dj + wo, ci]      # [Ho, Wo]
-                taps = w_ref[di, dj, ci, :]                      # [Cout]
-                acc += plane[:, :, None].astype(jnp.float32) * \
-                    taps[None, None, :].astype(jnp.float32)
-    acc_ref[...] = acc
+    accumulate_plane(x_ref, w_ref, acc_ref, kh, kw, wo)
 
-    @pl.when(ci_step == n_ci - 1)
+    @pl.when(ci_step == pl.num_programs(1) - 1)
     def _flush():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def sconv_od(x: jax.Array, w: jax.Array, *, cin_tile: int = 8,
+def sconv_od(x: jax.Array, w: jax.Array, *, cin_tile: int = LANE,
              interpret: bool = False) -> jax.Array:
-    """x [N,H,W,Cin], w [KH,KW,Cin,Cout] -> [N,Ho,Wo,Cout] (stride 1, VALID)."""
-    n, h, wd, cin = x.shape
+    """x [N,H,W,Cin], w [KH,KW,Cin,Cout] -> [N,Ho,Wo,Cout] (stride 1, VALID).
+
+    Compiled for a TPU, ``cin_tile`` must be a multiple of 128 or cover
+    the whole channel axis (the lane dim of the ifmap block)."""
+    n, _, _, cin = x.shape
     kh, kw, _, cout = w.shape
-    ho, wo = h - kh + 1, wd - kw + 1
-    # the channel grid covers ceil(cin / cin_tile) full tiles: prime
-    # channel counts zero-pad to the next tile boundary (zero ifmap
-    # channels contribute exactly nothing to the accumulator) instead of
-    # degrading to cin_tile=1
+    # the channel grid covers ceil(cin / cin_tile) full tiles: a channel
+    # count the tile does not divide zero-pads to the next tile boundary
+    # (zero ifmap channels contribute exactly nothing to the accumulator)
     cin_tile = min(cin_tile, cin)
     n_ci = pl.cdiv(cin, cin_tile)
     cin_pad = n_ci * cin_tile
     if cin_pad != cin:
         x = jnp.pad(x, ((0, 0), (0, 0), (0, 0), (0, cin_pad - cin)))
         w = jnp.pad(w, ((0, 0), (0, 0), (0, cin_pad - cin), (0, 0)))
-    grid = (n, n_ci)
+    x, ho, wo, ho_pad, wo_pad = pad_plane(x, kh, kw)
+    h, wd = x.shape[1], x.shape[2]
 
-    return pl.pallas_call(
-        functools.partial(_kernel, kh=kh, kw=kw, cin_tile=cin_tile),
-        grid=grid,
+    out = pl.pallas_call(
+        functools.partial(_kernel, kh=kh, kw=kw, wo=wo_pad),
+        grid=(n, n_ci),
         in_specs=[
             pl.BlockSpec((None, h, wd, cin_tile),
                          lambda b, c: (b, 0, 0, c)),
             pl.BlockSpec((kh, kw, cin_tile, cout),
                          lambda b, c: (0, 0, c, 0)),
         ],
-        out_specs=pl.BlockSpec((None, ho, wo, cout),
-                               lambda b, c: (b, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, ho, wo, cout), x.dtype),
-        scratch_shapes=[pltpu.VMEM((ho, wo, cout), jnp.float32)],
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        out_specs=pl.BlockSpec((None, ho_pad * wo_pad, cout),
+                               lambda b, c: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, ho_pad * wo_pad, cout), x.dtype),
+        scratch_shapes=[pltpu.VMEM((ho_pad * wo_pad, cout), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="sconv_od",
     )(x, w)
+    return out.reshape(n, ho_pad, wo_pad, cout)[:, :ho, :wo]

@@ -48,7 +48,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
+# the contractions run at the XLA Q-net's precision (HIGHEST), so the
+# compiled kernel tracks it to accumulation rounding
+from repro.core.flexai.dqn import PRECISION
+
 
 GRAD_CLIP = 10.0
 ADAM_B1, ADAM_B2, ADAM_EPS = 0.9, 0.999, 1e-8
@@ -56,17 +59,21 @@ ADAM_B1, ADAM_B2, ADAM_EPS = 0.9, 0.999, 1e-8
 
 def _forward(s, w1, b1, w2, b2, w3, b3):
     """2xReLU MLP + linear head, returning pre-activations for relu'."""
-    z1 = jax.lax.dot(s, w1, preferred_element_type=jnp.float32) + b1
+    z1 = jax.lax.dot(s, w1, precision=PRECISION,
+                     preferred_element_type=jnp.float32) + b1
     h1 = jnp.maximum(z1, 0.0)
-    z2 = jax.lax.dot(h1, w2, preferred_element_type=jnp.float32) + b2
+    z2 = jax.lax.dot(h1, w2, precision=PRECISION,
+                     preferred_element_type=jnp.float32) + b2
     h2 = jnp.maximum(z2, 0.0)
-    q = jax.lax.dot(h2, w3, preferred_element_type=jnp.float32) + b3
+    q = jax.lax.dot(h2, w3, precision=PRECISION,
+                    preferred_element_type=jnp.float32) + b3
     return z1, h1, z2, h2, q
 
 
 def _bdot(a, b):
     """[bt, M]^T @ [bt, N] -> [M, N] batch-contraction (MXU-friendly)."""
     return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               precision=PRECISION,
                                preferred_element_type=jnp.float32)
 
 
@@ -78,7 +85,7 @@ def _td_kernel(*refs, bt: int, B: int, gamma: float, lr: float,
     k = 17
     if fold_adam:
         mu_refs, nu_refs = refs[k:k + 6], refs[k + 6:k + 12]
-        step_ref = refs[k + 12]
+        corr_ref = refs[k + 12]
         k += 13
     loss_ref = refs[k]
     out_refs = refs[k + 1:k + 7]            # grads OR new params
@@ -127,9 +134,11 @@ def _td_kernel(*refs, bt: int, B: int, gamma: float, lr: float,
     g = -(1.0 / B) * jnp.clip(err, -1.0, 1.0)       # dL/dq_sel
     dq = g * oh_a
     dh2 = jax.lax.dot_general(dq, ew[4], (((1,), (1,)), ((), ())),
+                              precision=PRECISION,
                               preferred_element_type=jnp.float32) \
         * (z2 > 0.0).astype(jnp.float32)
     dh1 = jax.lax.dot_general(dh2, ew[2], (((1,), (1,)), ((), ())),
+                              precision=PRECISION,
                               preferred_element_type=jnp.float32) \
         * (z1 > 0.0).astype(jnp.float32)
     acc_refs[0][...] += _bdot(s, dh1)               # dW1
@@ -152,9 +161,8 @@ def _td_kernel(*refs, bt: int, B: int, gamma: float, lr: float,
             for o, a in zip(out_refs, acc_refs):
                 o[...] = a[...] * clip
         else:
-            step = (step_ref[0, 0] + 1).astype(jnp.float32)
-            c1 = 1.0 - ADAM_B1 ** step
-            c2 = 1.0 - ADAM_B2 ** step
+            # Adam bias corrections, computed outside: Mosaic has no pow
+            c1, c2 = corr_ref[:, 0:1], corr_ref[:, 1:2]
             for p, m_r, v_r, a, op, om, ov in zip(
                     refs[5:11], mu_refs, nu_refs, acc_refs,
                     out_refs, outm_refs, outv_refs):
@@ -175,7 +183,8 @@ def dqn_td_pallas(s, a, r, sn, done, eval_w, targ_w, *, gamma: float,
     s/sn [B, D] f32, a [B, 1] i32, r/done [B, 1] f32; ``eval_w``/
     ``targ_w`` are 6-tuples (w1 [D,H1], b1 [1,H1], w2, b2, w3, b3 [1,A]).
     Returns ``(loss [1,1], grads 6-tuple)`` — or, with ``adam=(mu6, nu6,
-    step [1,1] i32)``, ``(loss, new_params 6-tuple, new_mu, new_nu)``.
+    corr [1,2] f32)`` (the step's bias corrections ``1 - b1**t``,
+    ``1 - b2**t``), ``(loss, new_params 6-tuple, new_mu, new_nu)``.
     """
     B, d = s.shape
     fold_adam = adam is not None
@@ -199,10 +208,10 @@ def dqn_td_pallas(s, a, r, sn, done, eval_w, targ_w, *, gamma: float,
     in_specs += [pspec(sh) for sh in pshapes] * 2
     inputs = [s, a, r, sn, done, *eval_w, *targ_w]
     if fold_adam:
-        mu, nu, step = adam
+        mu, nu, corr = adam
         in_specs += [pspec(sh) for sh in pshapes] * 2 \
-            + [pspec((1, 1))]
-        inputs += [*mu, *nu, step]
+            + [pspec((1, 2))]
+        inputs += [*mu, *nu, corr]
 
     out_specs = [pspec((1, 1))] + [pspec(sh) for sh in pshapes]
     out_shape = [jax.ShapeDtypeStruct((1, 1), jnp.float32)] \
@@ -223,7 +232,7 @@ def dqn_td_pallas(s, a, r, sn, done, eval_w, targ_w, *, gamma: float,
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=scratch,
-        compiler_params=CompilerParams(dimension_semantics=("arbitrary",)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="dqn_td_update" if fold_adam else "dqn_td_grads",
     )(*inputs)

@@ -16,9 +16,8 @@ This layer owns the batch-dict plumbing: 1-D replay fields reshape to the
 2-D layouts Mosaic wants ([B] -> [B, 1], biases [H] -> [1, H]) and back.
 Batch padding to the tile grid lives in ``kernel.py`` (masked tail
 blocks).  ``interpret=None`` defers to
-:func:`repro.compat.pallas_interpret_default`, which honors the
-``REPRO_KERNEL_COMPILED`` hardware-run protocol (see
-``repro.kernels.protocol``).
+:func:`repro.compat.pallas_interpret_default`: interpreted on the CPU
+backend, compiled on any other (see ``repro.kernels.protocol``).
 """
 from __future__ import annotations
 
@@ -27,7 +26,7 @@ import jax.numpy as jnp
 from repro.compat import pallas_interpret_default
 from repro.core.flexai.dqn import AdamState, DQNParams
 
-from .kernel import dqn_td_pallas
+from .kernel import ADAM_B1, ADAM_B2, dqn_td_pallas
 
 # Default batch-row tile: one tile covers the engine's replay batches
 # (FlexAIConfig.batch_size <= 128 everywhere in the repo), so the grid is
@@ -87,11 +86,13 @@ def dqn_td_update_fused(eval_p: DQNParams, targ_p: DQNParams,
     s, a, r, sn, dn = _batch_2d(batch)
     mu = _params_2d(opt.mu)
     nu = _params_2d(opt.nu)
-    step = opt.step.astype(jnp.int32).reshape(1, 1)
+    # the bias corrections exactly as adam_apply computes them
+    t = (opt.step + 1).astype(jnp.float32)
+    corr = jnp.stack([1.0 - ADAM_B1 ** t, 1.0 - ADAM_B2 ** t]).reshape(1, 2)
     loss, new_p, new_mu, new_nu = dqn_td_pallas(
         s, a, r, sn, dn, _params_2d(eval_p), _params_2d(targ_p),
         gamma=gamma, batch_tile=batch_tile, interpret=interpret,
-        adam=(mu, nu, step), lr=lr)
+        adam=(mu, nu, corr), lr=lr)
     new_opt = AdamState(opt.step + 1,
                         _params_back(new_mu, eval_p),
                         _params_back(new_nu, eval_p))

@@ -1,56 +1,28 @@
-"""Hardware-run protocol for the Pallas kernel layer.
+"""How the Pallas kernel layer executes, for tests and benchmarks.
 
-The kernel suite has two honest execution modes:
+* **interpret** — on the CPU backend, which has no Pallas compiler: the
+  kernel body runs as ordinary XLA ops.  This validates the math against
+  the oracles (what the test suite runs on the CPU) and says nothing
+  about Mosaic lowering, VMEM budgets or speed.
+* **compiled** — on every other backend: the kernels lower through
+  Mosaic and run on the chip.  Only these timings mean anything.
 
-* **interpret** (always available): Pallas executes the kernel body as
-  ordinary XLA ops.  This validates the *math* — parity against the
-  pure-jnp/autodiff oracles — on any host, which is what CPU CI runs.
-  It validates nothing about Mosaic lowering, VMEM budgets or real tiles.
-* **compiled** (``REPRO_KERNEL_COMPILED=1`` on a TPU/GPU host): the same
-  call sites lower through Mosaic/Triton and run on the accelerator.
-  This is the only mode whose timings mean anything; CI runs it when the
-  hardware exists and otherwise prints an explicit SKIPPED line — a
-  kernel gate must never be silently green.
-
-``repro.compat.pallas_interpret_default`` consumes the same env contract
-(it is the default for every kernel's ``interpret=`` argument); this
-module is the introspection side used by tests, ``benchmarks/kernels.py``
-and ``scripts/ci.sh``.
+``repro.compat.pallas_interpret_default`` makes the choice; this module
+reports it.
 """
 from __future__ import annotations
 
-import os
-
 import jax
 
-
-def accelerator_platform() -> str | None:
-    """"tpu" / "gpu" when the default backend is one, else None."""
-    plat = jax.devices()[0].platform
-    return plat if plat in ("tpu", "gpu") else None
-
-
-def compiled_requested() -> bool:
-    """True when the env asked for the compiled hardware run."""
-    return os.environ.get("REPRO_KERNEL_COMPILED") == "1"
+from repro.compat import pallas_interpret_default
 
 
 def compiled_available() -> bool:
-    """True when kernels will actually run compiled: hardware present AND
-    either it is a TPU (compiles by default) or the compiled run was
-    requested explicitly.  ``REPRO_KERNEL_COMPILED=0`` vetoes both."""
-    from repro.compat import pallas_interpret_default
-    return not pallas_interpret_default() \
-        and accelerator_platform() is not None
+    """True when the kernels run compiled on this backend."""
+    return not pallas_interpret_default()
 
 
 def status() -> dict:
-    """Protocol stamp for BENCH_kernels.json and skip messages."""
-    plat = jax.devices()[0].platform
-    return {
-        "backend": plat,
-        "accelerator": accelerator_platform(),
-        "REPRO_KERNEL_COMPILED": os.environ.get("REPRO_KERNEL_COMPILED"),
-        "compiled_run": compiled_available(),
-        "mode": "compiled" if compiled_available() else "interpret",
-    }
+    """Execution stamp for BENCH_kernels.json and skip messages."""
+    return {"backend": jax.devices()[0].platform,
+            "mode": "compiled" if compiled_available() else "interpret"}

@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import CompilerParams
-
 
 def _kernel(u_ref, a_ref, b_ref, c_ref, y_ref, sfin_ref, state_ref, *,
             chunk: int):
@@ -98,7 +96,7 @@ def ssd_scan_flat(u: jax.Array, a: jax.Array, Bm: jax.Array, Cm: jax.Array,
             jax.ShapeDtypeStruct((g, n, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="ssd_scan",

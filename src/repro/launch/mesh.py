@@ -5,9 +5,8 @@ this module never touches jax device state.  The dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
 import; tests and benchmarks see the real (1-device) platform.
 
-Mesh construction goes through ``repro.compat.make_mesh``: on new JAX every
-axis is explicitly ``AxisType.Auto``; on 0.4.x (no ``AxisType``) the kwarg
-is dropped, which means the same thing.
+Mesh construction goes through ``repro.compat.make_mesh``, so every axis
+is ``AxisType.Auto``.
 """
 from __future__ import annotations
 

@@ -31,6 +31,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compat import enable_compile_cache
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.models.api import model_api
 from repro.serve.engine import FlexAIPlacementService, Request, ServeEngine
@@ -78,10 +79,23 @@ def _durable_mode(args) -> bool:
                 or args.serve_waves or args.inject_core is not None)
 
 
-def run_qos_placement_serving(args) -> int:
+def _route_params(args, i: int):
+    """Route ``i`` of a placement run: areas cycle UB, UHW, HW so a fleet
+    mixes the paper's three driving areas (each at its own Table-5
+    camera rates), and every route has its own seed."""
+    from repro.core.environment import Area, EnvironmentParams
+    areas = list(Area)
+    return EnvironmentParams(area=areas[i % len(areas)],
+                             route_km=args.route_km,
+                             rate_scale=args.rate_scale, seed=args.seed + i)
+
+
+def run_qos_placement_serving(args):
     """Deadline-aware placement serving: routes arrive over a virtual
     timeline and are admitted EDF (or bucket-FIFO) with Table-5-derived
     deadlines, aging, preemption and shedding (see ``repro.serve.qos``).
+    Returns the drained engine, or None when the flags conflict (the
+    reason is printed).
 
     Durability flags (``repro.serve.durability``): ``--snapshot-dir`` /
     ``--snapshot-every`` write crash-recovery snapshots on a segment
@@ -92,7 +106,7 @@ def run_qos_placement_serving(args) -> int:
     mid-run (``--no-degrade`` disables the graceful-degradation
     response), and ``--state-out`` writes the bit-exactness digest npz.
     """
-    from repro.core.environment import EnvironmentParams, build_task_queue
+    from repro.core.environment import build_task_queue
     from repro.core.flexai import FlexAIAgent, FlexAIConfig
     from repro.core.hmai import HMAIPlatform
     from repro.serve.qos import QoSConfig, QoSPlacementEngine
@@ -103,17 +117,17 @@ def run_qos_placement_serving(args) -> int:
               "durability flags (the snapshot format packs whole-wave "
               "checkpoints and crash replay needs the deterministic "
               "virtual clock)")
-        return 1
+        return None
     if args.stages > 1 and durable:
         print("--stages > 1 is incompatible with durability flags "
               "(pipeline waves checkpoint (state, ring); the snapshot "
               "format and fault-masked executors are single-stage)")
-        return 1
+        return None
     plat = HMAIPlatform(capacity_scale=args.rate_scale)
     if args.inject_core is not None and not (0 <= args.inject_core < plat.n):
         print(f"--inject-core {args.inject_core} out of range: the "
               f"platform has {plat.n} accelerators (valid: 0..{plat.n - 1})")
-        return 1
+        return None
     if args.stages > 1:
         # stage-level placement needs stage-shaped Q params
         from repro.core.pipeline import PipelineFlexAI
@@ -174,7 +188,7 @@ def run_qos_placement_serving(args) -> int:
             if args.stages > 1:
                 print("--shard is single-stage (pipeline waves have "
                       "their own 2-D mesh path)")
-                return 1
+                return None
             from repro.compat import make_mesh
             n_dev = len(jax.devices())
             mesh = make_mesh((n_dev,), ("routes",))
@@ -186,10 +200,7 @@ def run_qos_placement_serving(args) -> int:
         gap = args.arrival_gap if args.arrival_gap is not None else 0.05
         t = 0.0
         for i in range(args.routes):
-            queue = build_task_queue(EnvironmentParams(
-                route_km=args.route_km, rate_scale=args.rate_scale,
-                seed=args.seed + i))
-            eng.submit(queue, arrival=t)
+            eng.submit(build_task_queue(_route_params(args, i)), arrival=t)
             t += gap
     t0 = time.perf_counter()
     if durable and args.serve_waves:
@@ -209,6 +220,7 @@ def run_qos_placement_serving(args) -> int:
           f"routes in {dt:.2f}s wall ({s['virtual_time_s']:.3f}s virtual): "
           f"miss_rate {s['miss_rate']:.3f} shed {s['shed']} "
           f"preemptions {s['preemptions']} refills {s['refills']} "
+          f"dispatches {s['dispatches']} "
           f"p50_slack {s['p50_slack_s']:.4f}s "
           f"p99_slack {s['p99_slack_s']:.4f}s "
           f"mean_stm {s['mean_stm_rate']:.3f}")
@@ -220,7 +232,7 @@ def run_qos_placement_serving(args) -> int:
         if args.state_out:
             np.savez(args.state_out, **serving_digest(eng))
             print(f"state digest -> {args.state_out}")
-    return 0
+    return eng
 
 
 def run_placement_serving(args) -> int:
@@ -229,7 +241,7 @@ def run_placement_serving(args) -> int:
     ``--shard`` (run under ``--xla_force_host_platform_device_count=N``
     on CPU)."""
     from repro.compat import make_mesh
-    from repro.core.environment import EnvironmentParams, build_task_queue
+    from repro.core.environment import build_task_queue
     from repro.core.flexai import FlexAIAgent, FlexAIConfig
     from repro.core.hmai import HMAIPlatform
 
@@ -246,9 +258,8 @@ def run_placement_serving(args) -> int:
     svc = FlexAIPlacementService(plat, agent.learner.eval_p,
                                  min_bucket=args.min_bucket, mesh=mesh)
 
-    queues = [build_task_queue(EnvironmentParams(
-        route_km=args.route_km, rate_scale=args.rate_scale,
-        seed=args.seed + i)) for i in range(args.routes)]
+    queues = [build_task_queue(_route_params(args, i))
+              for i in range(args.routes)]
     n_tasks = sum(len(q) for q in queues)
     t0 = time.perf_counter()
     results = svc.place(queues)
@@ -260,7 +271,7 @@ def run_placement_serving(args) -> int:
     return 0
 
 
-def main(argv=None) -> int:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
     ap.add_argument("--smoke", action="store_true")
@@ -334,7 +345,14 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", action="store_true",
                     help="print per-segment/snapshot/fault progress lines")
     args = ap.parse_args(argv)
+    if not args.placement and args.arch is None:
+        ap.error("--arch is required unless --placement is given")
+    return args
 
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    enable_compile_cache()
     if args.placement:
         # any QoS- or durability-shaped flag (even an explicit default
         # value) routes to the deadline-aware wave engine; the plain
@@ -343,10 +361,8 @@ def main(argv=None) -> int:
                 or args.deadline_scale is not None or args.stages > 1
                 or args.continuous or args.measured_svc
                 or _durable_mode(args)):
-            return run_qos_placement_serving(args)
+            return 0 if run_qos_placement_serving(args) is not None else 1
         return run_placement_serving(args)
-    if args.arch is None:
-        ap.error("--arch is required unless --placement is given")
     return run_token_serving(args)
 
 

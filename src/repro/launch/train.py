@@ -21,10 +21,9 @@ benchmark checkpoints) — data-parallel over all visible devices with
 ``--td-kernel`` swaps the TD update inside the training scan for the
 fused Pallas kernel (``repro.kernels.dqn_update``): EvalNet forward,
 double-DQN target, Huber loss, hand-derived backward, global-norm clip
-and Adam in one VMEM-resident pass.  On CPU hosts it runs in interpret
-mode (numerics-faithful, not a speed claim); on TPU/GPU hosts set
-``REPRO_KERNEL_COMPILED=1`` to run the compiled Mosaic/Triton kernel
-(see ``repro.kernels.protocol`` and ``benchmarks/kernels.py``).
+and Adam in one VMEM-resident pass.  On the CPU backend it runs in
+interpret mode (numerics-faithful, not a speed claim); on a TPU it runs
+the compiled Mosaic kernel (see ``repro.kernels.protocol``).
 """
 from __future__ import annotations
 
@@ -36,6 +35,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compat import enable_compile_cache
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.models.api import model_api
 from repro.sharding import unbox
@@ -86,7 +86,7 @@ def run_flexai_training(args) -> int:
                          td_kernel=args.td_kernel)
     if args.td_kernel:
         from repro.compat import pallas_interpret_default
-        mode = ("interpret (CPU host — plain XLA ops, not a speed claim)"
+        mode = ("interpret (CPU backend — plain XLA ops, not a speed claim)"
                 if pallas_interpret_default() else "compiled")
         print(f"TD update: fused Pallas kernel, {mode}")
     if args.weights and os.path.exists(args.weights):
@@ -181,8 +181,8 @@ def main(argv=None) -> int:
     ap.add_argument("--td-kernel", action="store_true",
                     help="use the fused Pallas TD-update kernel "
                          "(kernels/dqn_update) inside the training scan; "
-                         "interpret mode on CPU hosts, compiled on "
-                         "TPU/GPU under REPRO_KERNEL_COMPILED=1")
+                         "interpret mode on the CPU backend, compiled "
+                         "on a TPU")
     ap.add_argument("--shard", action="store_true",
                     help="[flexai] shard lanes over all visible devices")
     ap.add_argument("--weights", default=None,
@@ -208,6 +208,7 @@ def main(argv=None) -> int:
                     choices=["none", "bf16", "int8_ef"])
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.flexai:
         if args.shard and not args.dp:

@@ -20,7 +20,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.models import layers as L
 from repro.models.config import ModelConfig
 from repro.sharding import with_logical_constraint as wlc
@@ -252,7 +251,7 @@ def moe_apply_shard_map(p: dict, cfg: ModelConfig, x: jax.Array, mesh):
         w = gates_loc.reshape(a_loc, 1).astype(dt)
         return jnp.sum((out_rep * w).reshape(n_loc, k, d), axis=1)
 
-    out_flat = shard_map(
+    out_flat = jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(tok_spec, tok_spec, tok_spec,
                   P("model", None, None), P("model", None, None),

@@ -144,7 +144,7 @@ def _masked_segment_fn(spec: PlatformSpec, backlog_scale: float, mesh=None):
     spec change (fault firing) recompiles."""
     key = (np.asarray(spec.exec_time).tobytes(),
            np.asarray(spec.energy).tobytes(), float(backlog_scale),
-           None if mesh is None else (mesh.devices.shape, mesh.axis_names))
+           mesh)
     if key not in _MASKED_FN_CACHE:
         run = _schedule_run_masked(spec, backlog_scale)
 
@@ -157,9 +157,8 @@ def _masked_segment_fn(spec: PlatformSpec, backlog_scale: float, mesh=None):
         else:
             from jax.sharding import PartitionSpec as P
 
-            from repro.compat import shard_map
             ax = mesh.axis_names[0]
-            _MASKED_FN_CACHE[key] = jax.jit(shard_map(
+            _MASKED_FN_CACHE[key] = jax.jit(jax.shard_map(
                 vm, mesh=mesh, in_specs=(P(), P(ax), P(ax), P()),
                 out_specs=(P(ax), P(ax))))
     return _MASKED_FN_CACHE[key]

@@ -125,7 +125,7 @@ def _segment_fn(spec, backlog_scale: float, mesh=None):
     pad lanes to the mesh size."""
     key = (np.asarray(spec.exec_time).tobytes(),
            np.asarray(spec.energy).tobytes(), float(backlog_scale),
-           None if mesh is None else (mesh.devices.shape, mesh.axis_names))
+           mesh)
 
     def build():
         from repro.core.flexai.engine import _schedule_run
@@ -135,11 +135,10 @@ def _segment_fn(spec, backlog_scale: float, mesh=None):
             return jax.jit(vm)
         from jax.sharding import PartitionSpec as P
 
-        from repro.compat import shard_map
         ax = mesh.axis_names[0]
-        return jax.jit(shard_map(vm, mesh=mesh,
-                                 in_specs=(P(), P(ax), P(ax)),
-                                 out_specs=(P(ax), P(ax))))
+        return jax.jit(jax.shard_map(vm, mesh=mesh,
+                                     in_specs=(P(), P(ax), P(ax)),
+                                     out_specs=(P(ax), P(ax))))
 
     return _seg_cache_get(key, build)
 

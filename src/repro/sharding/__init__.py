@@ -1,4 +1,4 @@
-from repro.compat import abstract_mesh, make_mesh
+from repro.compat import make_mesh
 from repro.sharding.partition import (
     Param,
     is_param,

@@ -82,10 +82,21 @@ def test_decode_rules_structure():
 
 
 def test_virtual_platform_schedules():
-    from repro.core.virtual_platform import VirtualPlatform
+    import jax
+    import pytest
+
+    from repro.core.virtual_platform import DEFAULT_POOLS, VirtualPlatform
     from repro.core.tasks import Task, TaskKind
-    plat = VirtualPlatform(run_real=False)
-    assert plat.n == 3
+    n_dev = len(jax.devices())
+    if n_dev < len(DEFAULT_POOLS):
+        # pools never share a device: too few devices is an error
+        with pytest.raises(ValueError, match="pools ask for"):
+            VirtualPlatform(run_real=False)
+    pools = DEFAULT_POOLS[:n_dev]
+    plat = VirtualPlatform(pool_specs=pools, run_real=False)
+    assert plat.n == len(pools)
+    assert [p.devices for p in plat.pools] == [
+        [d] for d in jax.devices()[:len(pools)]]
     assert all(p.measured_fps for p in plat.pools)
     rec = plat.execute(Task(uid=0, kind=TaskKind.YOLO, camera_group="FC",
                             camera_id=0, arrival_time=0.0, safety_time=5.0), 0)

@@ -169,6 +169,29 @@ def test_dp_chunked_collectives_match_legacy_trajectory():
                                    atol=1e-4)
 
 
+def test_dp_sharded_single_device_matches_unsharded():
+    """The shard_map DP trainer on a 1-device mesh walks the unsharded
+    trajectory bit-exactly, in both collective layouts.  One device
+    already type-checks shard_map's varying manual axes."""
+    from repro.compat import make_mesh
+    plat = _platform()
+    spec = spec_from_platform(plat)
+    cfg = _cfg()
+    batch = stack_task_arrays([tasks_to_arrays(_queue(s))
+                               for s in (61, 62)])
+    ts0 = dp_train_init(jax.random.PRNGKey(cfg.seed), 3 + 5 * plat.n,
+                        plat.n, cfg.replay_capacity, 2)
+    mesh = make_mesh((1,), ("routes",), devices=jax.devices()[:1])
+    for chunk in (True, False):
+        ref = make_dp_train_fn(spec, cfg, 2, chunk_collectives=chunk)(
+            ts0, batch)
+        out = make_dp_train_fn(spec, cfg, 2, mesh=mesh,
+                               chunk_collectives=chunk)(ts0, batch)
+        for a, b in zip(jax.tree_util.tree_leaves(out),
+                        jax.tree_util.tree_leaves(ref)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 @pytest.mark.slow
 def test_dp_sharded_matches_unsharded_equal_global_batch():
     """2-device shard_map DP == unsharded DP on the same 4-route global

@@ -7,8 +7,8 @@ over the Huber double-DQN loss + 10.0 global-norm clip, ``dqn_td_update``
 fuses everything into one Pallas pass, so every test here is a parity
 pin, not a behavior spec.
 
-Execution mode follows ``repro.kernels.protocol``: interpret on CPU,
-compiled under ``REPRO_KERNEL_COMPILED=1`` on TPU/GPU.
+Execution mode follows ``repro.kernels.protocol``: interpret on the CPU
+backend, compiled on any other.
 """
 import jax
 import jax.numpy as jnp
@@ -227,16 +227,15 @@ def test_kernel_inside_jit_scan_cond():
     assert float(losses[1]) == 0.0 and float(losses[0]) > 0.0
 
 
-def test_protocol_interpret_decision_table():
-    """The pure decision core of the REPRO_KERNEL_COMPILED contract."""
+@pytest.mark.parametrize("platform,interpret", [
+    ("cpu", True),     # no Pallas compiler on the CPU backend
+    ("tpu", False),    # Mosaic: the path every chip run takes
+    ("gpu", False),
+])
+def test_protocol_interpret_decision_table(platform, interpret):
+    """Pallas interprets if and only if the backend is the CPU."""
     from repro.compat import _interpret_for
-    assert _interpret_for("cpu", None) is True
-    assert _interpret_for("cpu", "1") is True    # no compiler on CPU
-    assert _interpret_for("tpu", None) is False  # Mosaic native
-    assert _interpret_for("tpu", "0") is True    # forced-interpret debug
-    assert _interpret_for("gpu", None) is True   # opt-in only
-    assert _interpret_for("gpu", "1") is False   # the hardware run
-    assert _interpret_for("gpu", "0") is True
+    assert _interpret_for(platform) is interpret
 
 
 # ---------------------------------------------------------------------------

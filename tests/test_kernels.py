@@ -1,10 +1,8 @@
 """Per-kernel correctness: shape/dtype sweeps vs pure-jnp oracles.
 
-Execution mode follows the hardware-run protocol
-(``repro.kernels.protocol``): interpret mode on CPU hosts (the kernel
-body executes as XLA ops), compiled Mosaic/Triton when
-``REPRO_KERNEL_COMPILED=1`` runs this suite on a TPU/GPU host — same
-tests, same tolerances, real tiles."""
+Execution mode follows ``repro.kernels.protocol``: interpret mode on the
+CPU backend (the kernel body executes as XLA ops), compiled Mosaic on a
+TPU — same tests, same tolerances, real tiles."""
 import math
 
 import jax
@@ -108,6 +106,48 @@ def test_sconv_ic_tall_ifmap_halo_window():
     out = sconv_ic(x, w, row_tile=8, interpret=INTERPRET)
     np.testing.assert_allclose(np.asarray(out), np.asarray(conv2d_ref(x, w)),
                                rtol=1e-4, atol=1e-4)
+
+
+def test_mconv_mc_pads_indivisible_channel_tiles():
+    """cin = 11 and cout = 13 with 8-channel tiles: both channel axes
+    zero-pad to 16, a 2 x 2 grid of channel-pair tiles, and the padded
+    output channels are sliced off."""
+    from repro.kernels.conv_dataflow.mconv_mc import mconv_mc
+    k1, k2 = jax.random.split(KEY)
+    x = jax.random.normal(k1, (2, 10, 9, 11), jnp.float32)
+    w = jax.random.normal(k2, (3, 3, 11, 13), jnp.float32) * 0.2
+    out = mconv_mc(x, w, cout_tile=8, cin_tile=8, interpret=INTERPRET)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(conv2d_ref(x, w)),
+                               rtol=1e-4, atol=1e-4)
+
+
+def _pallas_grids(jaxpr):
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield tuple(eqn.params["grid_mapping"].grid)
+        for v in eqn.params.values():
+            inner = getattr(v, "jaxpr", None)
+            if inner is not None:
+                yield from _pallas_grids(getattr(inner, "jaxpr", inner))
+
+
+@pytest.mark.parametrize("dataflow,grid", [
+    ("SconvOD", (1, 2)),        # 2 sequential Cin steps: 204 -> 256 = 2 x 128
+    ("SconvIC", (1, 4)),        # 4 output-row bands: 26 -> 32 = 4 x 8
+    ("MconvMC", (1, 4, 2)),     # 4 Cout x 2 Cin tiles: 409 -> 512, 204 -> 256
+])
+def test_conv_dataflow_grids_at_a_real_layer(dataflow, grid):
+    """A YOLO stage-512 3x3 conv at its spec width (204 -> 409 channels,
+    26 x 26 on a 416-pixel input): each dataflow keeps its own grid at
+    real widths — psums flow across sequential channel steps in SconvOD
+    and MconvMC, and MconvMC tiles channel pairs."""
+    from repro.models.perception.nets import YOLO_WIDTH
+    cin, cout = int(256 * YOLO_WIDTH), int(512 * YOLO_WIDTH)
+    x = jax.ShapeDtypeStruct((1, 26, 26, cin), jnp.float32)
+    w = jax.ShapeDtypeStruct((3, 3, cin, cout), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda a, b: conv2d(
+        a, b, dataflow=dataflow, padding="SAME", interpret=INTERPRET))(x, w)
+    assert list(_pallas_grids(jaxpr.jaxpr)) == [grid]
 
 
 ATTN_SHAPES = [

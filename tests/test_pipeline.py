@@ -278,6 +278,52 @@ def test_stage_flexai_trains_and_matches_task_agent_on_one_stage():
 # sharded engine (subprocess: forced host devices before jax imports)
 # ---------------------------------------------------------------------------
 
+def test_sharded_pipeline_single_device_parity():
+    """The pipeline's three shard_map paths on a 1-device mesh — the
+    stage-sharded wavefront, the population trainer and the DP trainer —
+    reproduce their unsharded twins bit-exactly.  One device already
+    type-checks shard_map's varying manual axes."""
+    from repro.compat import make_mesh
+    from repro.core.flexai.engine import dp_train_init, train_init
+    from repro.core.pipeline import (combine_stage_states,
+                                     make_pipeline_dp_train_fn,
+                                     make_pipeline_train_fn,
+                                     make_sharded_pipeline_fn,
+                                     make_sharded_pipeline_train_fn,
+                                     stage_state_dim)
+    plat = _platform()
+    spec = spec_from_platform(plat)
+    plan = build_stage_plan(plat, 1)
+    batch = stack_task_arrays([tasks_to_arrays(_queue(s))
+                               for s in (46, 47)])
+    one = jax.devices()[:1]
+
+    f_fl, _, r_fl = make_pipeline_schedule_fn(
+        spec, plan, policy="eft", batched=True)(None, batch)
+    mesh2 = make_mesh((1, 1), ("stages", "routes"), devices=one)
+    st, _, rc = make_sharded_pipeline_fn(spec, plan, mesh2,
+                                         policy="eft")(None, batch)
+    assert all(np.array_equal(np.asarray(a).transpose(1, 2, 0),
+                              np.asarray(b))
+               for a, b in zip(jax.tree_util.tree_leaves(rc),
+                               jax.tree_util.tree_leaves(r_fl)))
+    assert _trees_equal(combine_stage_states(plan, st), f_fl)
+
+    cfg = _cfg()
+    sd = stage_state_dim(plat.n)
+    mesh = make_mesh((1,), ("routes",), devices=one)
+    ts = jax.vmap(lambda k: train_init(k, sd, plat.n, cfg.replay_capacity))(
+        jax.random.split(jax.random.PRNGKey(cfg.seed), 2))
+    assert _trees_equal(
+        make_sharded_pipeline_train_fn(spec, plan, cfg, mesh)(ts, batch),
+        make_pipeline_train_fn(spec, plan, cfg, batched=True)(ts, batch))
+    ts = dp_train_init(jax.random.PRNGKey(cfg.seed), sd, plat.n,
+                       cfg.replay_capacity, 2)
+    assert _trees_equal(
+        make_pipeline_dp_train_fn(spec, plan, cfg, 2, mesh=mesh)(ts, batch),
+        make_pipeline_dp_train_fn(spec, plan, cfg, 2)(ts, batch))
+
+
 @pytest.mark.slow
 def test_sharded_pipeline_matches_flattened():
     script = textwrap.dedent("""

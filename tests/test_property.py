@@ -136,8 +136,10 @@ def test_error_feedback_compensates():
     ["batch", "embed", "heads", "mlp", "vocab", "expert", None]),
     min_size=1, max_size=4))
 def test_mesh_axes_never_reused(names):
-    from repro.sharding import DEFAULT_RULES, abstract_mesh
-    mesh = abstract_mesh((2, 2), ("data", "model"))
+    from jax.sharding import AbstractMesh
+
+    from repro.sharding import DEFAULT_RULES
+    mesh = AbstractMesh((2, 2), ("data", "model"))
     spec = logical_to_mesh_axes(tuple(names), DEFAULT_RULES, mesh)
     used = []
     for entry in spec:

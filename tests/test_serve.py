@@ -268,3 +268,17 @@ def test_sample_token_topk():
         t = sample_token(logits, jax.random.PRNGKey(seed), temperature=1.0,
                          top_k=2)
         assert int(t[0]) in (1, 3)
+
+
+@pytest.mark.parametrize("route,area", [(0, "UB"), (1, "UHW"), (2, "HW"),
+                                        (3, "UB"), (7, "UHW")])
+def test_placement_fleet_areas_cycle_by_route(route, area):
+    """A ``launch.serve --placement`` fleet mixes the paper's three areas
+    by route index (UB, UHW, HW, UB, ...); every route has its own seed
+    and the run's rate and length."""
+    from repro.launch.serve import _route_params, parse_args
+    args = parse_args(["--placement", "--routes", "8", "--seed", "5",
+                       "--rate-scale", "0.5", "--route-km", "0.02"])
+    p = _route_params(args, route)
+    assert p.area.value == area and p.seed == 5 + route
+    assert p.rate_scale == 0.5 and p.route_km == 0.02

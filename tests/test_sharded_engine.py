@@ -134,6 +134,81 @@ def test_placement_service_sharded_matches_unsharded():
     assert "OK" in out
 
 
+def test_sharded_engine_single_device_parity():
+    """shard_map over a 1-device ("routes",) mesh is a pure re-layout of
+    the vmapped greedy schedule and of the population trainer.  One
+    device already type-checks shard_map's varying manual axes, so this
+    pins the multi-device paths without forced host devices."""
+    import jax
+
+    from repro.compat import make_mesh
+    from repro.core.environment import EnvironmentParams, build_task_queue
+    from repro.core.flexai import FlexAIAgent, FlexAIConfig
+    from repro.core.flexai.engine import (make_schedule_fn,
+                                          make_sharded_schedule_fn,
+                                          make_sharded_train_fn,
+                                          make_train_fn, train_init)
+    from repro.core.hmai import HMAIPlatform
+    from repro.core.platform_jax import spec_from_platform
+
+    rs = 0.05
+    plat = HMAIPlatform(capacity_scale=rs)
+    spec = spec_from_platform(plat)
+    batch = pad_route_batch(stack_task_arrays([tasks_to_arrays(
+        build_task_queue(EnvironmentParams(
+            route_km=0.02, rate_scale=rs, seed=s, max_times_turn=2,
+            max_times_reverse=1, max_duration_turn=4.0,
+            max_duration_reverse=6.0))) for s in (51, 52)]), 1)
+    mesh = make_mesh((1,), ("routes",), devices=jax.devices()[:1])
+
+    def assert_same(a, b):
+        for x, y in zip(jax.tree_util.tree_leaves(jax.device_get(a)),
+                        jax.tree_util.tree_leaves(jax.device_get(b))):
+            np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+    params = FlexAIAgent(plat, FlexAIConfig(seed=3)).learner.eval_p
+    assert_same(make_sharded_schedule_fn(spec, mesh)(params, batch),
+                make_schedule_fn(spec, batched=True)(params, batch))
+
+    cfg = FlexAIConfig(min_replay=32, batch_size=16, update_every=4,
+                       eps_decay_steps=500, replay_capacity=1024, seed=4)
+    ts = jax.vmap(lambda k: train_init(k, 3 + 5 * plat.n, plat.n,
+                                       cfg.replay_capacity))(
+        jax.random.split(jax.random.PRNGKey(cfg.seed), 2))
+    assert_same(make_sharded_train_fn(spec, cfg, mesh)(ts, batch),
+                make_train_fn(spec, cfg, batched=True)(ts, batch))
+
+
+def test_benchmark_arm_runs_in_process(monkeypatch):
+    """The path a chip takes for a multi-device benchmark arm: the same
+    ``--child`` body in this process over the first devices, its tagged
+    result parsed from captured stdout."""
+    import jax
+
+    from benchmarks.common import run_device_arm
+    from benchmarks.sharded_engine import RESULT_TAG
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    res = run_device_arm(
+        "sharded_engine", 1,
+        ["--lanes", 2, "--tasks", 32, "--iters", 1, "--unique-routes", 2],
+        RESULT_TAG)
+    assert res["devices"] == 1 and res["lanes"] == 2
+    assert res["placements_equal"] and res["metric_rel_diff_max"] < 1e-4
+
+
+def test_forced_device_child_refused_off_cpu(monkeypatch):
+    """Off the CPU backend the parent holds the chip, so a child that
+    needs it would fail or hang: spawning one is refused up front."""
+    import jax
+
+    from benchmarks.common import spawn_forced_device_child
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="CPU backend only"):
+        spawn_forced_device_child("sharded_engine", 2, [], "UNUSED ")
+
+
 def test_pad_route_batch_shapes_and_validity():
     routes = [invalid_task_arrays(10) for _ in range(3)]
     for i, r in enumerate(routes):

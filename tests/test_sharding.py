@@ -10,8 +10,9 @@ import textwrap
 import jax
 import numpy as np
 import pytest
+from jax.sharding import AbstractMesh
 
-from repro.sharding import (DEFAULT_RULES, Param, abstract_mesh, boxed_axes,
+from repro.sharding import (DEFAULT_RULES, Param, boxed_axes,
                             logical_to_mesh_axes, unbox)
 
 
@@ -39,12 +40,12 @@ def test_eval_shape_keeps_boxes():
 
 
 def test_multipod_axis_resolution():
-    mesh = abstract_mesh((2, 4, 4), ("pod", "data", "model"))
+    mesh = AbstractMesh((2, 4, 4), ("pod", "data", "model"))
     spec = logical_to_mesh_axes(("batch", None, "mlp"), DEFAULT_RULES, mesh)
     assert spec[0] == ("pod", "data")
     assert spec[2] == "model"
     # single-pod mesh: the "pod" component is dropped transparently
-    mesh1 = abstract_mesh((4, 4), ("data", "model"))
+    mesh1 = AbstractMesh((4, 4), ("data", "model"))
     spec1 = logical_to_mesh_axes(("batch", None, "mlp"), DEFAULT_RULES, mesh1)
     assert spec1[0] == "data"
 
