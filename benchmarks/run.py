@@ -1,5 +1,5 @@
-"""Benchmark driver: one module per paper table/figure + kernel micro +
-roofline.  Prints ``name,us_per_call,derived`` CSV per row and writes the
+"""Benchmark runner: one module per paper table/figure + kernel micro.
+Prints ``name,us_per_call,derived`` CSV per row and writes the
 full JSON per module to experiments/bench/.
 
     PYTHONPATH=src python -m benchmarks.run [--full] [--only MOD]
@@ -29,7 +29,6 @@ MODULES = [
     "pipeline",
     "kernel_micro",
     "kernels",
-    "roofline",
     "recovery",
     "scenarios",
 ]

@@ -195,6 +195,9 @@ def run_qos_placement_serving(args):
             print(f"QoS wave mesh: {n_dev} device(s) on axis 'routes'")
         eng = QoSPlacementEngine(plat, params, cfg,
                                  backlog_scale=backlog_scale, mesh=mesh)
+    if args.trace:
+        from repro.serve.tracing import Tracer
+        eng.tracer = Tracer()
 
     if not args.resume:
         gap = args.arrival_gap if args.arrival_gap is not None else 0.05
@@ -215,6 +218,9 @@ def run_qos_placement_serving(args):
             eng.snapshot()
             eng.saver.wait()
     dt = time.perf_counter() - t0
+    if eng.tracer is not None:
+        print(eng.tracer.line(), flush=True)
+        eng.tracer = None
     s = eng.stats()
     print(f"qos[{s['policy']}] served {s['completed']}/{s['submitted']} "
           f"routes in {dt:.2f}s wall ({s['virtual_time_s']:.3f}s virtual): "
@@ -343,7 +349,10 @@ def parse_args(argv=None) -> argparse.Namespace:
                     help="wall sleep per segment (widens the kill window "
                          "for the crash-recovery subprocess test)")
     ap.add_argument("--trace", action="store_true",
-                    help="print per-segment/snapshot/fault progress lines")
+                    help="QoS wave engine: attach a span tracer and print "
+                         "its summary at exit (per span: count, total and "
+                         "self ms; transfer counters), plus per-segment, "
+                         "snapshot and fault progress lines")
     args = ap.parse_args(argv)
     if not args.placement and args.arch is None:
         ap.error("--arch is required unless --placement is given")
@@ -359,7 +368,7 @@ def main(argv=None) -> int:
         # batch service has no timeline for them to act on
         if (args.qos is not None or args.arrival_gap is not None
                 or args.deadline_scale is not None or args.stages > 1
-                or args.continuous or args.measured_svc
+                or args.continuous or args.measured_svc or args.trace
                 or _durable_mode(args)):
             return 0 if run_qos_placement_serving(args) is not None else 1
         return run_placement_serving(args)

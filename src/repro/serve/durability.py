@@ -60,6 +60,7 @@ from repro.core.platform_jax import (PlatformSpec, PlatformState,
 from repro.core.tasks import TaskArrays, pad_route_batch
 from repro.serve.qos import (COMPLETED, PREEMPTED, QoSConfig,
                              QoSPlacementEngine, RouteRequest, Wave)
+from repro.serve.tracing import OFF
 from repro.train import checkpoint as ckpt_lib
 from repro.train.fault_tolerance import (HeartbeatRecord, PreemptionGuard,
                                          StragglerDetector)
@@ -666,9 +667,11 @@ class DurableQoSEngine(QoSPlacementEngine):
         # Deferring the device transfers to the writer thread measures
         # worse, not better: hundreds of background device_gets contend
         # with serving's own dispatches on the GIL and the jax runtime.
-        arrays, meta = pack_engine(self, inflight=inflight)
-        self.saver.save(self.snapshots_written,
-                        encode_snapshot(arrays, meta))
+        tr = self.tracer
+        with OFF if tr is None else tr.span("snapshot"):
+            arrays, meta = pack_engine(self, inflight=inflight)
+            self.saver.save(self.snapshots_written,
+                            encode_snapshot(arrays, meta))
         self.snapshot_time_s += time.perf_counter() - t0
         if self.trace:
             print(f"SNAPSHOT step={self.segments_done} "

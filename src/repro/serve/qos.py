@@ -66,6 +66,15 @@ Two production paths land on top (ISSUE 10):
   so EDF ordering and the aging starvation bound survive; the refilled
   lane's ``PlatformState`` row is reinitialized, and the wave remains a
   preemptible checkpointed unit with per-lane cursors.
+
+Tracing: ``engine.tracer = serve.tracing.Tracer()`` records the loop's
+spans — ``admit`` (children ``admit.pack_tasks``, ``admit.init_state``),
+``segment`` (``segment.slice``, ``segment.call``, ``hook`` around
+``_after_segment``), ``drain`` (``drain.records``, ``drain.state``,
+``drain.summarize``, ``hook`` around ``_on_complete``) and one ``queued``
+per request — with its transfer counters and the requests' ``t_submit``
+/ ``t_admit`` / ``t_done``.  ``None``, the default, costs one test per
+site.
 """
 from __future__ import annotations
 
@@ -86,6 +95,7 @@ from repro.core.tasks import (TaskArrays, invalid_task_arrays,
                               stack_task_arrays, tasks_to_arrays)
 from repro.serve.policy import (QoSPolicy, effective_deadline,
                                 power_of_two_bucket)
+from repro.serve.tracing import OFF
 
 __all__ = [
     "QoSConfig", "QoSPlacementEngine", "RouteRequest", "Wave", "QoSPolicy",
@@ -131,14 +141,17 @@ def _segment_fn(spec, backlog_scale: float, mesh=None):
         from repro.core.flexai.engine import _schedule_run
         run = _schedule_run(spec, backlog_scale)
         vm = jax.vmap(run, in_axes=(None, 0, 0))
-        if mesh is None:
-            return jax.jit(vm)
-        from jax.sharding import PartitionSpec as P
+        if mesh is not None:
+            from jax.sharding import PartitionSpec as P
 
-        ax = mesh.axis_names[0]
-        return jax.jit(jax.shard_map(vm, mesh=mesh,
-                                     in_specs=(P(), P(ax), P(ax)),
-                                     out_specs=(P(ax), P(ax))))
+            ax = mesh.axis_names[0]
+            vm = jax.shard_map(vm, mesh=mesh, in_specs=(P(), P(ax), P(ax)),
+                               out_specs=(P(ax), P(ax)))
+
+        def placement_segment(params, tasks, state):
+            return vm(params, tasks, state)
+
+        return jax.jit(placement_segment)
 
     return _seg_cache_get(key, build)
 
@@ -156,7 +169,12 @@ def _pipeline_segment_fn(spec, plan, backlog_scale: float):
         from repro.core.pipeline import _pipeline_segment_run
         run = _pipeline_segment_run(spec, plan, backlog_scale,
                                     policy="flexai")
-        return jax.jit(jax.vmap(run, in_axes=(None, 0, None, 0, 0)))
+        vm = jax.vmap(run, in_axes=(None, 0, None, 0, 0))
+
+        def pipeline_segment(params, tasks, stages, state, ring):
+            return vm(params, tasks, stages, state, ring)
+
+        return jax.jit(pipeline_segment)
 
     return _seg_cache_get(key, build)
 
@@ -229,6 +247,11 @@ class RouteRequest:
     finish: Optional[float] = None
     slack: Optional[float] = None
     summary: Optional[dict] = None
+    # host perf_counter seconds, set only while a tracer is attached:
+    # submitted, first admitted to a wave, placements on the host
+    t_submit: Optional[float] = None
+    t_admit: Optional[float] = None
+    t_done: Optional[float] = None
 
     @property
     def missed(self) -> bool:
@@ -360,6 +383,20 @@ class QoSPlacementEngine:
         self.dispatches = 0
         self.preemption_count = 0
         self.refills = 0
+        self._tracer = None
+
+    @property
+    def tracer(self):
+        """The attached ``serve.tracing.Tracer``, or None (off)."""
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        if self._tracer is not None:
+            self._tracer.detach()
+        self._tracer = tracer
+        if tracer is not None:
+            tracer.attach()
 
     # ------------------------------------------------------------------
     # submission
@@ -427,6 +464,8 @@ class QoSPlacementEngine:
                            n_tasks=n, arrival=float(arrival),
                            deadline=float(deadline), bucket=bucket,
                            submit_order=self._order)
+        if self._tracer is not None:
+            req.t_submit = time.perf_counter_ns() * 1e-9
         self._order += 1
         if req.arrival <= self.now:
             self.backlog.append(req)
@@ -485,18 +524,24 @@ class QoSPlacementEngine:
         self.qpolicy.age(self.preempted)
         for r in wave_reqs:
             r.status = RUNNING
-        rows = [r.tasks for r in wave_reqs]
-        rows += [invalid_task_arrays(head.bucket)
-                 for _ in range(self.cfg.slots - len(rows))]
-        batch = stack_task_arrays(rows)
-        state = stack_states(
-            [platform_init(self.spec.n) for _ in range(self.cfg.slots)])
-        self.wave_log.append([r.uid for r in wave_reqs])
+        tr = self._tracer
         s_seq = ring = flat_len = None
-        if self.plan is not None:
-            batch, s_seq, flat_len = self._flatten_batch(batch, head.bucket)
-            import jax.numpy as jnp
-            ring = jnp.zeros((self.cfg.slots, self.cfg.stages), jnp.float32)
+        with OFF if tr is None else tr.span("admit.pack_tasks"):
+            rows = [r.tasks for r in wave_reqs]
+            rows += [invalid_task_arrays(head.bucket)
+                     for _ in range(self.cfg.slots - len(rows))]
+            batch = stack_task_arrays(rows)
+            if self.plan is not None:
+                batch, s_seq, flat_len = self._flatten_batch(batch,
+                                                             head.bucket)
+        with OFF if tr is None else tr.span("admit.init_state"):
+            state = stack_states(
+                [platform_init(self.spec.n) for _ in range(self.cfg.slots)])
+            if self.plan is not None:
+                import jax.numpy as jnp
+                ring = jnp.zeros((self.cfg.slots, self.cfg.stages),
+                                 jnp.float32)
+        self._admitted(wave_reqs)
         # the wave inherits its members' earned aging credit, so a
         # long-aged request that gets preempted right after admission does
         # not restart its anti-starvation clock from zero
@@ -525,7 +570,36 @@ class QoSPlacementEngine:
              np.zeros(flat_len - s_seq.shape[0], s_seq.dtype)])
         return stack_task_arrays(lanes), s_seq, flat_len
 
+    def _admitted(self, reqs: list) -> None:
+        """Log one admission round; with a tracer, stamp the first
+        admission of each request and record its ``queued`` span."""
+        self.wave_log.append([r.uid for r in reqs])
+        tr = self._tracer
+        if tr is None:
+            return
+        tr.count("waves_admitted")
+        wid = len(self.wave_log) - 1
+        t = time.perf_counter_ns()
+        for r in reqs:
+            if r.t_admit is None:
+                r.t_admit = t * 1e-9
+                if r.t_submit is not None:
+                    tr.record("queued", round(r.t_submit * 1e9), t,
+                              wave=wid, uid=r.uid)
+
     def _next_wave(self) -> Optional[Wave]:
+        """Admit the next wave (None when nothing is left to serve)."""
+        tr = self._tracer
+        if tr is None:
+            return self._select_wave()
+        # the round about to be logged, so the children carry it too
+        with tr.span("admit", wave=len(self.wave_log)) as sp:
+            wave = self._select_wave()
+            if wave is None:
+                sp.wave = None
+        return wave
+
+    def _select_wave(self) -> Optional[Wave]:
         while True:
             self._promote_arrivals()
             if not self.backlog and not self.preempted:
@@ -567,7 +641,7 @@ class QoSPlacementEngine:
         self.qpolicy.age(self.preempted)
         for r in wave.requests:
             r.status = RUNNING
-        self.wave_log.append([r.uid for r in wave.requests])
+        self._admitted(wave.requests)
         return wave
 
     # ------------------------------------------------------------------
@@ -592,13 +666,23 @@ class QoSPlacementEngine:
 
     # ---- durability seams (overridden by serve/durability.py) ----------
 
+    def _stage_slice(self, wave: Wave) -> np.ndarray:
+        """The stage sequence of a pipeline wave's next segment."""
+        return wave.s_seq[wave.progress: wave.progress + self.cfg.chunk]
+
     def _dispatch_segment(self, wave: Wave, seg: TaskArrays):
-        """Serve one chunk: returns ``(new_state, records)``.  The
-        durability layer swaps in fault-masked / mesh-sharded executors
-        here without touching the wave loop.  With a mesh the lane axis
-        is padded to the mesh size (invalid rows + fresh states) and
-        trimmed back — per-lane scans are independent, so sharding is
+        """Serve one chunk: returns ``(new_state, records)``; a pipeline
+        segment also advances the wave's ring.  The durability layer
+        swaps in fault-masked / mesh-sharded executors here without
+        touching the wave loop.  With a mesh the lane axis is padded to
+        the mesh size (invalid rows + fresh states) and trimmed back —
+        per-lane scans are independent, so sharding is
         placement-neutral."""
+        if self.plan is not None:
+            state, wave.ring, recs = self._seg_fn(
+                self.params, seg, self._stage_slice(wave), wave.state,
+                wave.ring)
+            return state, recs
         if self.mesh is not None:
             pad = (-self.cfg.slots) % self.mesh.size
             if pad:
@@ -616,18 +700,25 @@ class QoSPlacementEngine:
         return self._seg_fn(self.params, seg, wave.state)
 
     def _timed_dispatch(self, wave: Wave, seg: TaskArrays):
-        """Dispatch one segment, measuring wall time when the measured
-        service clock is armed: the blocking ``perf_counter`` window
-        feeds the per-(bucket, stages) EMA and is what ``_charge_segment``
-        advances the clock by for this segment."""
-        if not self.cfg.measured_svc:
-            return self._dispatch_segment(wave, seg)
-        t0 = time.perf_counter()
-        out = self._dispatch_segment(wave, seg)
-        jax.block_until_ready(out[0])
-        self._seg_elapsed = time.perf_counter() - t0
-        self._observe_service(wave.bucket, self._seg_elapsed)
-        return out
+        """Dispatch one segment (the ``segment.call`` span), measuring
+        wall time when the measured service clock is armed: the blocking
+        ``perf_counter`` window feeds the per-(bucket, stages) EMA and is
+        what ``_charge_segment`` advances the clock by for this
+        segment."""
+        tr = self._tracer
+        with OFF if tr is None else tr.span("segment.call"):
+            if tr is not None:
+                tr.to_device(self.params, seg, wave.state, *(
+                    () if self.plan is None
+                    else (self._stage_slice(wave), wave.ring)))
+            if not self.cfg.measured_svc:
+                return self._dispatch_segment(wave, seg)
+            t0 = time.perf_counter()
+            out = self._dispatch_segment(wave, seg)
+            jax.block_until_ready(out[0])
+            self._seg_elapsed = time.perf_counter() - t0
+            self._observe_service(wave.bucket, self._seg_elapsed)
+            return out
 
     def _observe_service(self, bucket: int, elapsed: float) -> None:
         per_slot = elapsed / self.cfg.chunk
@@ -666,70 +757,92 @@ class QoSPlacementEngine:
         chunk = self.cfg.chunk
         total = wave.flat_len if wave.flat_len is not None else wave.bucket
         while wave.progress < total:
-            p = wave.progress
-            seg = jax.tree_util.tree_map(
-                lambda a: a[:, p: p + chunk], wave.batch)
-            if self.plan is not None:
-                t0 = (time.perf_counter() if self.cfg.measured_svc
-                      else None)
-                state, ring, recs = self._seg_fn(
-                    self.params, seg, wave.s_seq[p: p + chunk],
-                    wave.state, wave.ring)
-                if t0 is not None:
-                    jax.block_until_ready(state)
-                    self._seg_elapsed = time.perf_counter() - t0
-                    self._observe_service(wave.bucket, self._seg_elapsed)
-                wave.ring = ring
-            else:
+            tr = self._tracer
+            with (OFF if tr is None
+                  else tr.span("segment", wave=len(self.wave_log) - 1)):
+                p = wave.progress
+                with OFF if tr is None else tr.span("segment.slice"):
+                    seg = jax.tree_util.tree_map(
+                        lambda a: a[:, p: p + chunk], wave.batch)
                 state, recs = self._timed_dispatch(wave, seg)
-            self.dispatches += 1
-            wave.state = state
-            wave.recs.append(recs)
-            wave.progress += chunk
-            self._charge_segment(wave, recs)
-            self._promote_arrivals()
-            self._after_segment(wave)
-            if self._halt:
-                return  # durability stop: the wave was snapshotted in-flight
-            if wave.progress < total and self._should_preempt(wave):
-                wave.preemptions += 1
-                self.preemption_count += 1
-                for r in wave.requests:
-                    r.status = PREEMPTED
-                self.preempted.append(wave)
-                return
-        # wave drained: every live lane completes at the current clock
-        recs = jax.tree_util.tree_map(
-            lambda *xs: np.concatenate([np.asarray(x) for x in xs], axis=1),
-            *wave.recs)
-        final = jax.device_get(wave.state)
-        order = None
-        if self.plan is not None:
-            from repro.core.pipeline import _record_order
-            order = np.asarray(_record_order(wave.bucket, self.cfg.stages))
-        for lane, req in enumerate(wave.requests):
-            lane_final = jax.tree_util.tree_map(lambda a: a[lane], final)
-            lane_recs = jax.tree_util.tree_map(lambda a: a[lane], recs)
-            if order is not None:
-                # flat wavefront records -> task-major [bucket, S];
-                # end-to-end verdicts come from the final stage
-                from repro.core.pipeline import pipeline_summarize
-                lane_recs = jax.tree_util.tree_map(
-                    lambda a: a[order], lane_recs)
-                summ = pipeline_summarize(self.spec, lane_final, lane_recs)
-                summ["placements"] = np.asarray(
-                    lane_recs.action)[: req.n_tasks]       # [n_tasks, S]
-            else:
-                summ = summarize(self.spec, lane_final, lane_recs)
-                summ["placements"] = np.asarray(
-                    lane_recs.action)[: req.n_tasks]
-            summ["bucket"] = wave.bucket
-            req.summary = summ
-            req.status = COMPLETED
-            req.finish = self.now
-            req.slack = req.deadline - self.now
+                self.dispatches += 1
+                wave.state = state
+                wave.recs.append(recs)
+                wave.progress += chunk
+                self._charge_segment(wave, recs)
+                self._promote_arrivals()
+                with OFF if tr is None else tr.span("hook"):
+                    self._after_segment(wave)
+                if self._halt:
+                    return  # durability stop: the wave was snapshotted
+                if wave.progress < total and self._should_preempt(wave):
+                    wave.preemptions += 1
+                    self.preemption_count += 1
+                    for r in wave.requests:
+                        r.status = PREEMPTED
+                    self.preempted.append(wave)
+                    return
+        self._drain_wave(wave)
+
+    def _drain_wave(self, wave: Wave) -> None:
+        """Wave drained: bring its records and final state to the host;
+        every live lane completes at the current clock."""
+        tr = self._tracer
+        with (OFF if tr is None
+              else tr.span("drain", wave=len(self.wave_log) - 1)):
+            with OFF if tr is None else tr.span("drain.records"):
+                if tr is not None:
+                    tr.to_host(wave.recs)
+                recs = jax.tree_util.tree_map(
+                    lambda *xs: np.concatenate([np.asarray(x) for x in xs],
+                                               axis=1),
+                    *wave.recs)
+            with OFF if tr is None else tr.span("drain.state"):
+                if tr is not None:
+                    tr.to_host(wave.state)
+                final = jax.device_get(wave.state)
+            order = None
+            if self.plan is not None:
+                from repro.core.pipeline import _record_order
+                order = np.asarray(_record_order(wave.bucket,
+                                                 self.cfg.stages))
+            for lane, req in enumerate(wave.requests):
+                with OFF if tr is None else tr.span("drain.summarize",
+                                                    uid=req.uid):
+                    lane_final = jax.tree_util.tree_map(lambda a: a[lane],
+                                                        final)
+                    lane_recs = jax.tree_util.tree_map(lambda a: a[lane],
+                                                       recs)
+                    if order is not None:
+                        # flat wavefront records -> task-major [bucket, S];
+                        # end-to-end verdicts come from the final stage
+                        from repro.core.pipeline import pipeline_summarize
+                        lane_recs = jax.tree_util.tree_map(
+                            lambda a: a[order], lane_recs)
+                        summ = pipeline_summarize(self.spec, lane_final,
+                                                  lane_recs)
+                    else:
+                        summ = summarize(self.spec, lane_final, lane_recs)
+                    # [n_tasks] placements ([n_tasks, S] for pipelines)
+                    summ["placements"] = np.asarray(
+                        lane_recs.action)[: req.n_tasks]
+                    summ["bucket"] = wave.bucket
+                self._finish(req, summ, lane_final, lane_recs)
+
+    def _finish(self, req: RouteRequest, summ: dict, lane_final,
+                lane_recs) -> None:
+        """A request's placements are on the host: complete it (the
+        ``_on_complete`` hook runs in a ``hook`` span)."""
+        req.summary = summ
+        req.status = COMPLETED
+        req.finish = self.now
+        req.slack = req.deadline - self.now
+        tr = self._tracer
+        if tr is not None:
+            req.t_done = time.perf_counter_ns() * 1e-9
+        with OFF if tr is None else tr.span("hook", uid=req.uid):
             self._on_complete(req, lane_final, lane_recs)
-            self.completed.append(req)
+        self.completed.append(req)
 
     # ---- continuous batching (cfg.continuous) --------------------------
 
@@ -750,28 +863,33 @@ class QoSPlacementEngine:
             wave.lane_recs = [[] for _ in range(slots)]
         idle_row = invalid_task_arrays(chunk)
         while True:
-            rows = []
-            for lane in range(slots):
-                r = wave.lane_requests[lane]
-                if r is None:
-                    rows.append(idle_row)
-                else:
-                    p = wave.lane_progress[lane]
-                    rows.append(jax.tree_util.tree_map(
-                        lambda a: a[p: p + chunk], r.tasks))
-            seg = stack_task_arrays(rows)
-            state, recs = self._timed_dispatch(wave, seg)
-            self.dispatches += 1
-            wave.state = state
-            for lane in range(slots):
-                if wave.lane_requests[lane] is not None:
-                    wave.lane_recs[lane].append(jax.tree_util.tree_map(
-                        lambda a: a[lane], recs))
-                    wave.lane_progress[lane] += chunk
-            wave.progress += chunk
-            self._charge_segment(wave, recs)
-            self._promote_arrivals()
-            self._after_segment(wave)
+            tr = self._tracer
+            with (OFF if tr is None
+                  else tr.span("segment", wave=len(self.wave_log) - 1)):
+                with OFF if tr is None else tr.span("segment.slice"):
+                    rows = []
+                    for lane in range(slots):
+                        r = wave.lane_requests[lane]
+                        if r is None:
+                            rows.append(idle_row)
+                        else:
+                            p = wave.lane_progress[lane]
+                            rows.append(jax.tree_util.tree_map(
+                                lambda a: a[p: p + chunk], r.tasks))
+                    seg = stack_task_arrays(rows)
+                state, recs = self._timed_dispatch(wave, seg)
+                self.dispatches += 1
+                wave.state = state
+                for lane in range(slots):
+                    if wave.lane_requests[lane] is not None:
+                        wave.lane_recs[lane].append(jax.tree_util.tree_map(
+                            lambda a: a[lane], recs))
+                        wave.lane_progress[lane] += chunk
+                wave.progress += chunk
+                self._charge_segment(wave, recs)
+                self._promote_arrivals()
+                with OFF if tr is None else tr.span("hook"):
+                    self._after_segment(wave)
             if self._halt:
                 wave.requests = [r for r in wave.lane_requests
                                  if r is not None]
@@ -798,20 +916,26 @@ class QoSPlacementEngine:
         """One lane reached its bucket: summarize exactly like a drained
         wave's lane and free the slot for refill."""
         r = wave.lane_requests[lane]
-        lane_recs = jax.tree_util.tree_map(
-            lambda *xs: np.concatenate([np.asarray(x) for x in xs]),
-            *wave.lane_recs[lane])
-        lane_final = jax.tree_util.tree_map(
-            lambda a: a[lane], jax.device_get(wave.state))
-        summ = summarize(self.spec, lane_final, lane_recs)
-        summ["placements"] = np.asarray(lane_recs.action)[: r.n_tasks]
-        summ["bucket"] = wave.bucket
-        r.summary = summ
-        r.status = COMPLETED
-        r.finish = self.now
-        r.slack = r.deadline - self.now
-        self._on_complete(r, lane_final, lane_recs)
-        self.completed.append(r)
+        tr = self._tracer
+        with (OFF if tr is None
+              else tr.span("drain", wave=len(self.wave_log) - 1)):
+            with OFF if tr is None else tr.span("drain.records"):
+                if tr is not None:
+                    tr.to_host(wave.lane_recs[lane])
+                lane_recs = jax.tree_util.tree_map(
+                    lambda *xs: np.concatenate([np.asarray(x) for x in xs]),
+                    *wave.lane_recs[lane])
+            with OFF if tr is None else tr.span("drain.state"):
+                if tr is not None:
+                    tr.to_host(wave.state)
+                lane_final = jax.tree_util.tree_map(
+                    lambda a: a[lane], jax.device_get(wave.state))
+            with OFF if tr is None else tr.span("drain.summarize",
+                                                uid=r.uid):
+                summ = summarize(self.spec, lane_final, lane_recs)
+                summ["placements"] = np.asarray(lane_recs.action)[: r.n_tasks]
+                summ["bucket"] = wave.bucket
+            self._finish(r, summ, lane_final, lane_recs)
         wave.lane_requests[lane] = None
         wave.lane_progress[lane] = 0
         wave.lane_recs[lane] = []
@@ -864,9 +988,18 @@ class QoSPlacementEngine:
                 if wave.lane_requests[lane] is None]
         if not free:
             return
+        tr = self._tracer
+        with (OFF if tr is None
+              else tr.span("admit", wave=len(self.wave_log))) as sp:
+            admitted = self._refill_lanes(wave, free)
+            if tr is not None and not admitted:
+                sp.wave = None
+
+    def _refill_lanes(self, wave: Wave, free: list) -> list:
         if self.qpolicy.is_edf and self.cfg.shed:
             self._shed_infeasible()
         import jax.numpy as jnp
+        tr = self._tracer
         admitted = []
         for lane in free:
             head = self._refill_head(wave)
@@ -877,17 +1010,19 @@ class QoSPlacementEngine:
             wave.lane_requests[lane] = head
             wave.lane_progress[lane] = 0
             wave.lane_recs[lane] = []
-            wave.state = jax.tree_util.tree_map(
-                lambda a, b: jnp.asarray(a).at[lane].set(b),
-                wave.state, platform_init(self.spec.n))
+            with OFF if tr is None else tr.span("admit.init_state"):
+                wave.state = jax.tree_util.tree_map(
+                    lambda a, b: jnp.asarray(a).at[lane].set(b),
+                    wave.state, platform_init(self.spec.n))
             admitted.append(head)
         if admitted:
             self.refills += len(admitted)
-            self.wave_log.append([r.uid for r in admitted])
+            self._admitted(admitted)
             self.qpolicy.age(self.backlog)
             self.qpolicy.age(self.preempted)
             wave.waves_waited = max(
                 [wave.waves_waited] + [r.waves_waited for r in admitted])
+        return admitted
 
     def run_until_done(self, max_waves: int = 100_000) -> None:
         for _ in range(max_waves):
