@@ -291,6 +291,20 @@ class Wave:
                    for r in self.requests)
 
 
+def _start_host_copy(tree) -> int:
+    """Start the device-to-host copy of every ``jax.Array`` leaf of
+    ``tree`` and return at once; returns how many were started.  A later
+    ``np.asarray`` / ``jax.device_get`` of the leaf reads the landed
+    buffer instead of paying its own blocking round trip.  NumPy leaves
+    (the stub executor's) are already on the host and are skipped."""
+    n = 0
+    for x in jax.tree_util.tree_leaves(tree):
+        if isinstance(x, jax.Array):
+            x.copy_to_host_async()
+            n += 1
+    return n
+
+
 def _stub_executor(spec):
     """State pass-through executor: same shapes as the scan dispatch, zero
     device work.  Lets the property suite exercise the queueing discipline
@@ -765,6 +779,13 @@ class QoSPlacementEngine:
                     seg = jax.tree_util.tree_map(
                         lambda a: a[:, p: p + chunk], wave.batch)
                 state, recs = self._timed_dispatch(wave, seg)
+                # the records travel to the host behind the device's
+                # compute while the host dispatches on; the drain reads
+                # buffers that have landed
+                early = _start_host_copy(recs)
+                if tr is not None:
+                    tr.to_host(recs)
+                    tr.count("d2h_early", early)
                 self.dispatches += 1
                 wave.state = state
                 wave.recs.append(recs)
@@ -786,17 +807,16 @@ class QoSPlacementEngine:
 
     def _drain_wave(self, wave: Wave) -> None:
         """Wave drained: bring its records and final state to the host;
-        every live lane completes at the current clock."""
+        every live lane completes at the current clock.  The records'
+        copies were started at dispatch (``_run_wave``), where they are
+        counted."""
         tr = self._tracer
         with (OFF if tr is None
               else tr.span("drain", wave=len(self.wave_log) - 1)):
             with OFF if tr is None else tr.span("drain.records"):
-                if tr is not None:
-                    tr.to_host(wave.recs)
                 recs = jax.tree_util.tree_map(
-                    lambda *xs: np.concatenate([np.asarray(x) for x in xs],
-                                               axis=1),
-                    *wave.recs)
+                    lambda *xs: np.concatenate(xs, axis=1),
+                    *jax.device_get(wave.recs))
             with OFF if tr is None else tr.span("drain.state"):
                 if tr is not None:
                     tr.to_host(wave.state)

@@ -18,13 +18,14 @@ Tracer()``; ``None`` detaches it) records, in memory:
   :meth:`Tracer.summary` can report their deltas over an interval.
 
 The counting rule: ``d2h_transfers`` / ``d2h_bytes`` count the
-``jax.Array`` leaves the engine brings to the host with ``np.asarray``
-or ``jax.device_get`` at its record and state drain sites, one transfer
-per leaf; ``h2d_transfers`` / ``h2d_bytes`` count the ``np.ndarray``
-leaves handed to a jitted segment call (params, task slice, state and,
-for pipeline waves, the stage slice and ring), each uploaded once by
-the call; ``waves_admitted`` counts admission rounds, one per
-``wave_log`` entry.
+``jax.Array`` leaves the engine brings to the host, one transfer per
+leaf: a drained wave's records where their copy starts, at dispatch
+(``d2h_early`` counts these early starts alone), its state and a
+continuous lane's records at the drain; ``h2d_transfers`` /
+``h2d_bytes`` count the ``np.ndarray`` leaves handed to a jitted
+segment call (params, task slice, state and, for pipeline waves, the
+stage slice and ring), each uploaded once by the call;
+``waves_admitted`` counts admission rounds, one per ``wave_log`` entry.
 
 While a tracer is attached to an engine, every garbage collection of
 the process is recorded as a ``gc`` span, whose parent is the span open
