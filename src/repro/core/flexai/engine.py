@@ -17,9 +17,19 @@ dispatch per TD step.  Here the whole route runs inside a single
 ``ScanFlexAI`` is the host-side convenience wrapper mirroring
 ``FlexAIAgent``'s train/schedule surface on top of these functions.
 See DESIGN.md ("Scan-body layout").
+
+The jitted training episode is XLA module ``jit_train_episode`` and the
+greedy eval ``jit_eval_episode``, the names by which a device trace
+attributes their time.  ``ScanFlexAI.tracer = serve.tracing.Tracer()``
+records the host side of each episode (spans ``episode`` >
+``episode.upload``, ``episode.call``, ``episode.fetch``,
+``episode.summarize``, and ``eval``) with the counters ``episodes``,
+``train_steps``, ``td_updates`` and the transfer counters; ``None``, the
+default, costs one test per site.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import time
 from typing import NamedTuple
@@ -43,6 +53,20 @@ from repro.core.platform_jax import (PlatformSpec, kind_feature_table,
                                      summarize, with_health)
 from repro.core.tasks import (TaskArrays, pad_task_arrays,
                               stack_task_arrays, tasks_to_arrays)
+
+# the context every span site enters when no tracer is attached (as
+# serve.tracing.OFF, which this module cannot import: repro.serve imports
+# this module)
+OFF = contextlib.nullcontext()
+
+
+def _jit_named(name: str, fn):
+    """``jax.jit`` of ``fn`` as XLA module ``jit_<name>``: a stable name
+    by which a device trace attributes the module's time."""
+    def named(*args, **kwargs):
+        return fn(*args, **kwargs)
+    named.__name__ = named.__qualname__ = name
+    return jax.jit(named)
 
 
 # ---------------------------------------------------------------------------
@@ -314,7 +338,7 @@ def make_train_fn(spec: PlatformSpec, cfg, batched: bool = False,
                 return jax.vmap(single, in_axes=(0, 0))(ts, tasks)
             return jax.vmap(lambda s, t, h: single(s, t, health=h),
                             in_axes=(0, 0, 0))(ts, tasks, health)
-    return jax.jit(run)
+    return _jit_named("train_episode", run)
 
 
 def make_sharded_train_fn(spec: PlatformSpec, cfg, mesh,
@@ -333,7 +357,7 @@ def make_sharded_train_fn(spec: PlatformSpec, cfg, mesh,
                    in_axes=(0, 0))
     sharded = jax.shard_map(run, mesh=mesh, in_specs=(P(axis), P(axis)),
                             out_specs=P(axis))
-    return jax.jit(sharded)
+    return _jit_named("train_episode", sharded)
 
 
 # ---------------------------------------------------------------------------
@@ -581,9 +605,9 @@ def make_dp_train_fn(spec: PlatformSpec, cfg, lanes: int, mesh=None,
     optimizer step instead of every scan step (see ``_dp_train_run``).
     """
     if mesh is None:
-        return jax.jit(_dp_train_run(spec, cfg, lanes,
-                                     chunk_collectives=chunk_collectives,
-                                     td_kernel=td_kernel))
+        return _jit_named("train_episode", _dp_train_run(
+            spec, cfg, lanes, chunk_collectives=chunk_collectives,
+            td_kernel=td_kernel))
     from jax.sharding import PartitionSpec as P
 
     if lanes < 1 or lanes % mesh.size:
@@ -597,7 +621,7 @@ def make_dp_train_fn(spec: PlatformSpec, cfg, lanes: int, mesh=None,
                           env_steps=P(), updates=P(), key=P())
     sharded = jax.shard_map(run, mesh=mesh, in_specs=(ts_specs, P(axis)),
                             out_specs=(ts_specs, P(axis), P(axis), P(), P()))
-    return jax.jit(sharded)
+    return _jit_named("train_episode", sharded)
 
 
 # ---------------------------------------------------------------------------
@@ -678,6 +702,22 @@ class ScanFlexAI:
         # so a snapshot/resume cycle keeps the best-so-far candidate
         self._best_stm: float = -1.0
         self._best_params: DQNParams | None = None
+        # the last episode's (records, losses, update_mask), on the host
+        self.last_episode = None
+        self._tracer = None
+
+    @property
+    def tracer(self):
+        """The attached ``serve.tracing.Tracer``, or None (off)."""
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        if self._tracer is not None:
+            self._tracer.detach()
+        self._tracer = tracer
+        if tracer is not None:
+            tracer.attach()
 
     def _as_arrays(self, tasks) -> TaskArrays:
         return tasks if isinstance(tasks, TaskArrays) else \
@@ -702,12 +742,34 @@ class ScanFlexAI:
             ta = self._as_arrays(tasks)
             if self.dp:  # the DP runner always carries a [lanes, T] axis
                 ta = TaskArrays(*[np.asarray(f)[None] for f in ta])
-        if health is None:
-            self.ts, plat, recs, losses, upd = self._train_fn(self.ts, ta)
-        else:
-            self.ts, plat, recs, losses, upd = self._train_fn(
-                self.ts, ta, health=jnp.asarray(health, jnp.float32))
-        losses, upd = np.asarray(losses), np.asarray(upd, bool)
+        tr = self._tracer
+        with OFF if tr is None else tr.span("episode"):
+            with OFF if tr is None else tr.span("episode.upload"):
+                if tr is not None:
+                    tr.to_device(ta)
+                ta = jax.device_put(ta)
+            with OFF if tr is None else tr.span("episode.call"):
+                if health is None:
+                    out = self._train_fn(self.ts, ta)
+                else:
+                    out = self._train_fn(
+                        self.ts, ta, health=jnp.asarray(health, jnp.float32))
+                jax.block_until_ready(out)
+            self.ts, plat, recs, losses, upd = out
+            with OFF if tr is None else tr.span("episode.fetch"):
+                if tr is not None:
+                    tr.to_host((recs, losses, upd))
+                recs, losses, upd = jax.device_get((recs, losses, upd))
+            upd = np.asarray(upd, bool)
+            self.last_episode = (recs, losses, upd)
+            if tr is not None:
+                tr.count("episodes")
+                tr.count("train_steps", int(np.asarray(recs.valid).sum()))
+                tr.count("td_updates", int(upd.sum()))
+            with OFF if tr is None else tr.span("episode.summarize"):
+                return self._summarize_episode(plat, recs, losses, upd)
+
+    def _summarize_episode(self, plat, recs, losses, upd) -> dict:
         if upd.any():
             self.losses.extend(losses[upd].tolist())
         if self.dp:
@@ -751,7 +813,10 @@ class ScanFlexAI:
         into EvalNet/TargNet once training ends.
 
         ``on_episode(ep, trainer)`` fires after each episode (snapshot
-        cadence hook); ``start_episode`` resumes mid-run — route cycling
+        cadence hook, after that episode's eval); returning True ends the
+        loop there, as if ``episodes`` had been reached — a caller with a
+        clock closes its window on an episode boundary.  ``start_episode``
+        resumes mid-run — route cycling
         and the eval cadence are indexed by the *global* episode number,
         so a restored run consumes exactly the episodes the uninterrupted
         run would have (the bit-exact resume contract; model-selection
@@ -783,15 +848,17 @@ class ScanFlexAI:
                     for i in range(per_lane)]
                 history.append(self.train_episode(lane_routes))
             if ta_eval is not None and (ep + 1) % eval_every == 0:
-                stms = self._eval_stms(ta_eval)
+                with OFF if self._tracer is None else \
+                        self._tracer.span("eval"):
+                    stms = self._eval_stms(ta_eval)
                 history[-1]["eval_stm"] = (
                     stms[0] if len(stms) == 1 else stms)
                 lane = int(np.argmax(stms))
                 if stms[lane] > self._best_stm:
                     self._best_stm = stms[lane]
                     self._best_params = self.eval_params(lane)
-            if on_episode is not None:
-                on_episode(ep, self)
+            if on_episode is not None and on_episode(ep, self) is True:
+                break
         if self._best_params is not None:
             self.set_params(self._best_params)
             self.best_eval_stm = self._best_stm
@@ -802,13 +869,15 @@ class ScanFlexAI:
         set: one entry for the shared agent (single-lane / DP), one per
         lane for population training (params vmapped over lanes, queue
         broadcast — a single device dispatch either way)."""
-        if self.dp or self.lanes == 1:
-            final, recs = self._sched_fn(self.eval_params(), ta_eval)
-            return [summarize(self.spec, final, recs)["stm_rate"]]
+        shared = self.dp or self.lanes == 1
         if self._eval_fn is None:
-            self._eval_fn = jax.jit(jax.vmap(
-                _schedule_run(self.spec, self.cfg.backlog_scale),
-                in_axes=(0, None)))
+            run = _schedule_run(self.spec, self.cfg.backlog_scale)
+            if not shared:
+                run = jax.vmap(run, in_axes=(0, None))
+            self._eval_fn = _jit_named("eval_episode", run)
+        if shared:
+            final, recs = self._eval_fn(self.eval_params(), ta_eval)
+            return [summarize(self.spec, final, recs)["stm_rate"]]
         finals, recs = self._eval_fn(self.ts.eval_p, ta_eval)
         return [summarize(
             self.spec,

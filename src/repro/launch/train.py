@@ -62,6 +62,27 @@ def _trainer_snapshot(trainer, episode: int) -> dict:
     }
 
 
+def build_flexai_trainer(*, seed: int = 0, lr: float = 1e-3,
+                         rate_scale: float = 1.0, lanes: int = 1,
+                         mesh=None, dp: bool = False,
+                         td_kernel: bool = False):
+    """The ``ScanFlexAI`` trainer as ``--flexai`` trains it: the HMAI
+    platform at ``rate_scale`` of its Table-8 capacity (the camera-rate
+    factor of the routes), Q-net weights from ``seed``, and the
+    launcher's ``FlexAIConfig`` (gamma 0.98, replay warm-up 256, a TD
+    update every second step, epsilon decay over 40,000 steps, TargNet
+    sync every 500 updates)."""
+    from repro.core.flexai import FlexAIConfig, ScanFlexAI
+    from repro.core.hmai import HMAIPlatform
+
+    cfg = FlexAIConfig(lr=lr, gamma=0.98, min_replay=256, update_every=2,
+                       eps_decay_steps=40_000, target_sync_every=500,
+                       seed=seed)
+    plat = HMAIPlatform(capacity_scale=rate_scale)
+    return ScanFlexAI(plat, cfg, lanes=lanes, mesh=mesh, dp=dp,
+                      td_kernel=td_kernel)
+
+
 def run_flexai_training(args) -> int:
     """Device-resident FlexAI training: fused episodes, optional
     data-parallel sharding, eval-based model selection, npz checkpoint
@@ -69,21 +90,16 @@ def run_flexai_training(args) -> int:
     from repro.compat import make_mesh
     from repro.core.environment import (Area, EnvironmentParams,
                                         build_task_queue)
-    from repro.core.flexai import FlexAIConfig, ScanFlexAI
-    from repro.core.hmai import HMAIPlatform
 
-    cfg = FlexAIConfig(lr=args.lr, gamma=0.98, min_replay=256,
-                       update_every=2, eps_decay_steps=40_000,
-                       target_sync_every=500, seed=args.seed)
-    plat = HMAIPlatform(capacity_scale=args.rate_scale)
     mesh = None
     if args.shard:
         n_dev = len(jax.devices())
         mesh = make_mesh((n_dev,), ("routes",))
         print(f"training mesh: {n_dev} device(s) on axis 'routes'")
     lanes = args.dp_lanes if args.dp else 1
-    trainer = ScanFlexAI(plat, cfg, lanes=lanes, mesh=mesh, dp=args.dp,
-                         td_kernel=args.td_kernel)
+    trainer = build_flexai_trainer(
+        seed=args.seed, lr=args.lr, rate_scale=args.rate_scale, lanes=lanes,
+        mesh=mesh, dp=args.dp, td_kernel=args.td_kernel)
     if args.td_kernel:
         from repro.compat import pallas_interpret_default
         mode = ("interpret (CPU backend — plain XLA ops, not a speed claim)"

@@ -1,7 +1,9 @@
-"""Spans and transfer counters of the QoS serving loop.
+"""Spans and transfer counters of the QoS serving loop and of the fused
+FlexAI trainer.
 
-A :class:`Tracer` attached to a ``QoSPlacementEngine`` (``engine.tracer =
-Tracer()``; ``None`` detaches it) records, in memory:
+A :class:`Tracer` attached to a ``QoSPlacementEngine`` or a
+``core.flexai.ScanFlexAI`` trainer (``engine.tracer = Tracer()``;
+``None`` detaches it) records, in memory:
 
 * spans ``(name, start_ns, end_ns, parent, wave, uid)`` on
   ``time.perf_counter_ns``.  ``parent`` is the index of the enclosing
@@ -26,6 +28,11 @@ continuous lane's records at the drain; ``h2d_transfers`` /
 segment call (params, task slice, state and, for pipeline waves, the
 stage slice and ring), each uploaded once by the call;
 ``waves_admitted`` counts admission rounds, one per ``wave_log`` entry.
+In the trainer the same rule counts an episode's task arrays uploaded
+(``episode.upload``) and its records, losses and update mask brought to
+the host (``episode.fetch``); ``episodes`` counts fused episodes,
+``train_steps`` the valid tasks they trained on and ``td_updates`` the
+TD updates they made (the update mask's sum).
 
 While a tracer is attached to an engine, every garbage collection of
 the process is recorded as a ``gc`` span, whose parent is the span open
@@ -50,7 +57,7 @@ OFF = contextlib.nullcontext()
 
 
 class Span:
-    """One interval of the serving loop, and its own context manager."""
+    """One interval of a traced loop, and its own context manager."""
     __slots__ = ("name", "start_ns", "end_ns", "parent", "wave", "uid",
                  "_tracer", "_ann")
 

@@ -157,3 +157,28 @@ def test_qos_segment_sharded_compiles_for_v5e_2x2(topo,
                            NamedSharding(mesh, P("routes")))
     compiled = seg.lower(*args).compile()
     assert compiled.memory_analysis() is not None
+
+
+def test_train_episode_with_td_kernel_compiles_for_v5e(one_chip, monkeypatch,
+                                                       no_persistent_cache):
+    """The training cell's device program: one fused FlexAI episode of
+    32,768 steps with the compiled TD-update kernel inside the scan's
+    ``lax.cond``, at the launcher's batch 64 and replay ring of 50,000."""
+    from repro.core.flexai.engine import make_train_fn, train_init
+    from repro.core.tasks import invalid_task_arrays
+    from repro.kernels.dqn_update import ops
+    from repro.launch.train import build_flexai_trainer
+    monkeypatch.setattr(ops, "pallas_interpret_default", lambda: False)
+    cfg = build_flexai_trainer(td_kernel=True).cfg
+    fn = make_train_fn(spec_from_platform(HMAIPlatform()), cfg,
+                       td_kernel=True)
+    ts = jax.eval_shape(lambda: train_init(
+        jax.random.PRNGKey(0), STATE_DIM, N_CORES, cfg.replay_capacity))
+    tasks = jax.eval_shape(lambda: invalid_task_arrays(32768))
+    on_chip = lambda tree: jax.tree_util.tree_map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        tree)
+    compiled = fn.lower(on_chip(ts), on_chip(tasks)).compile()
+    text = compiled.as_text()
+    assert "HloModule jit_train_episode" in text
+    assert "dqn_td_update" in text and "tpu_custom_call" in text

@@ -285,3 +285,65 @@ def test_flexai_trainer_snapshot_resume_bit_exact(tmp_path):
         assert sorted(a.files) == sorted(b.files)
         for k in a.files:
             np.testing.assert_array_equal(a[k], b[k])
+
+
+def _flexai_route(n: int, seed: int):
+    from repro.core.tasks import TaskArrays
+    rng = np.random.default_rng(seed)
+    return TaskArrays(
+        kind=rng.integers(0, 3, n).astype(np.int32),
+        arrival=np.sort(rng.uniform(0, 0.01 * n, n)).astype(np.float32),
+        safety=np.full(n, 0.05, np.float32),
+        group=np.zeros(n, np.int32), valid=np.ones(n, bool))
+
+
+def test_flexai_on_episode_ends_the_loop_only_when_it_returns_true():
+    """The launcher's trainer, driven through ``ScanFlexAI.train``: a hook
+    that returns None sees every episode and leaves the episodes, the
+    eval cadence, the weights and a snapshot taken in it as they are
+    without it; one that returns True after episode k leaves what a run
+    of k + 1 episodes leaves."""
+    from repro.launch.train import _trainer_snapshot, build_flexai_trainer
+    routes = [_flexai_route(300, s) for s in (1, 2, 3)]
+    held_out = _flexai_route(300, 9)
+
+    def train(episodes, hook=None, start=0, trainer=None):
+        tr = trainer or build_flexai_trainer(seed=7, rate_scale=0.05)
+        hist = tr.train(routes, episodes, eval_queue=held_out, eval_every=2,
+                        on_episode=hook, start_episode=start)
+        return tr, hist
+
+    def same_weights(a, b):
+        for x, y in zip(jax.device_get(a.eval_params()),
+                        jax.device_get(b.eval_params())):
+            np.testing.assert_array_equal(x, y)
+
+    plain, h_plain = train(5)
+    assert [("eval_stm" in h) for h in h_plain] == [False, True, False,
+                                                    True, False]
+    seen, snaps = [], {}
+
+    def observe(ep, tr):
+        seen.append(ep)
+        if ep == 2:
+            snaps[ep + 1] = jax.device_get(_trainer_snapshot(tr, ep + 1))
+
+    hooked, h_hooked = train(5, observe)
+    assert seen == [0, 1, 2, 3, 4]
+    assert h_hooked == h_plain and hooked.losses == plain.losses
+    same_weights(hooked, plain)
+
+    stopped, h_stopped = train(5, lambda ep, tr: ep == 2)
+    short, h_short = train(3)
+    assert len(h_stopped) == 3 and h_stopped == h_short
+    same_weights(stopped, short)
+
+    snap = snaps[3]
+    resumed = build_flexai_trainer(seed=7, rate_scale=0.05)
+    resumed.ts = jax.device_put(snap["ts"])
+    if bool(snap["has_best"]):
+        resumed._best_stm = float(snap["best_stm"])
+        resumed._best_params = jax.device_put(snap["best_p"])
+    _, h_resumed = train(5, start=3, trainer=resumed)
+    assert h_resumed == h_plain[3:]
+    same_weights(resumed, plain)
