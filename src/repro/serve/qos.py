@@ -72,9 +72,11 @@ spans — ``admit`` (children ``admit.pack_tasks``, ``admit.init_state``),
 ``segment`` (``segment.slice``, ``segment.call``, ``hook`` around
 ``_after_segment``), ``drain`` (``drain.records``, ``drain.state``,
 ``drain.summarize``, ``hook`` around ``_on_complete``) and one ``queued``
-per request — with its transfer counters and the requests' ``t_submit``
-/ ``t_admit`` / ``t_done``.  ``None``, the default, costs one test per
-site.
+per request — with its transfer counters, ``waves_admitted``,
+``fresh_state_reuses`` (fresh waves that took the engine's constant
+checkpoint in ``admit.init_state``; resumed waves and continuous refills
+do not count) and the requests' ``t_submit`` / ``t_admit`` / ``t_done``.
+``None``, the default, costs one test per site.
 """
 from __future__ import annotations
 
@@ -380,6 +382,16 @@ class QoSPlacementEngine:
             self._seg_fn = executor
         else:
             self._seg_fn = _segment_fn(self.spec, backlog_scale, mesh=mesh)
+        # a fresh wave's checkpoint is one constant: nothing donates or
+        # writes into a state leaf (a wave's state is only replaced), so
+        # every wave ``_pack_wave`` admits takes these same arrays
+        self._fresh_state = stack_states(
+            [platform_init(self.spec.n)] * cfg.slots)
+        self._fresh_ring = None
+        if cfg.stages > 1:
+            import jax.numpy as jnp
+            self._fresh_ring = jnp.zeros((cfg.slots, cfg.stages),
+                                         jnp.float32)
         # measured service times: per-(bucket, stages) EMA of wall-clock
         # per-slot segment cost (cfg.measured_svc); None entries fall
         # back to the virtual constant until the first dispatch lands
@@ -539,7 +551,7 @@ class QoSPlacementEngine:
         for r in wave_reqs:
             r.status = RUNNING
         tr = self._tracer
-        s_seq = ring = flat_len = None
+        s_seq = flat_len = None
         with OFF if tr is None else tr.span("admit.pack_tasks"):
             rows = [r.tasks for r in wave_reqs]
             rows += [invalid_task_arrays(head.bucket)
@@ -549,12 +561,9 @@ class QoSPlacementEngine:
                 batch, s_seq, flat_len = self._flatten_batch(batch,
                                                              head.bucket)
         with OFF if tr is None else tr.span("admit.init_state"):
-            state = stack_states(
-                [platform_init(self.spec.n) for _ in range(self.cfg.slots)])
-            if self.plan is not None:
-                import jax.numpy as jnp
-                ring = jnp.zeros((self.cfg.slots, self.cfg.stages),
-                                 jnp.float32)
+            state, ring = self._fresh_state, self._fresh_ring
+            if tr is not None:
+                tr.count("fresh_state_reuses")
         self._admitted(wave_reqs)
         # the wave inherits its members' earned aging credit, so a
         # long-aged request that gets preempted right after admission does

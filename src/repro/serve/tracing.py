@@ -27,7 +27,11 @@ continuous lane's records at the drain; ``h2d_transfers`` /
 ``h2d_bytes`` count the ``np.ndarray`` leaves handed to a jitted
 segment call (params, task slice, state and, for pipeline waves, the
 stage slice and ring), each uploaded once by the call;
-``waves_admitted`` counts admission rounds, one per ``wave_log`` entry.
+``waves_admitted`` counts admission rounds, one per ``wave_log`` entry;
+``fresh_state_reuses`` counts the fresh waves that took the engine's
+constant initial checkpoint (a resumed wave keeps its own, and a
+continuous refill resets one lane), so in drain mode without
+preemptions it equals ``waves_admitted``.
 In the trainer the same rule counts an episode's task arrays uploaded
 (``episode.upload``) and its records, losses and update mask brought to
 the host (``episode.fetch``); ``episodes`` counts fused episodes,
