@@ -25,11 +25,11 @@ records the host side of each episode (spans ``episode`` >
 ``episode.upload``, ``episode.call``, ``episode.fetch``,
 ``episode.summarize``, and ``eval``) with the counters ``episodes``,
 ``train_steps``, ``td_updates`` and the transfer counters; ``None``, the
-default, costs one test per site.
+default, leaves ``serve.tracing.DETACHED`` in place, whose calls do
+nothing.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import time
 from typing import NamedTuple
@@ -53,12 +53,6 @@ from repro.core.platform_jax import (PlatformSpec, kind_feature_table,
                                      summarize, with_health)
 from repro.core.tasks import (TaskArrays, pad_task_arrays,
                               stack_task_arrays, tasks_to_arrays)
-
-# the context every span site enters when no tracer is attached (as
-# serve.tracing.OFF, which this module cannot import: repro.serve imports
-# this module)
-OFF = contextlib.nullcontext()
-
 
 def _jit_named(name: str, fn):
     """``jax.jit`` of ``fn`` as XLA module ``jit_<name>``: a stable name
@@ -704,20 +698,21 @@ class ScanFlexAI:
         self._best_params: DQNParams | None = None
         # the last episode's (records, losses, update_mask), on the host
         self.last_episode = None
-        self._tracer = None
+        # serve.tracing imports the serve package, which imports this
+        # module: bound here, not at the top
+        from repro.serve.tracing import DETACHED
+        self._detached = self._tracer = DETACHED
 
     @property
     def tracer(self):
         """The attached ``serve.tracing.Tracer``, or None (off)."""
-        return self._tracer
+        return None if self._tracer is self._detached else self._tracer
 
     @tracer.setter
     def tracer(self, tracer) -> None:
-        if self._tracer is not None:
-            self._tracer.detach()
-        self._tracer = tracer
-        if tracer is not None:
-            tracer.attach()
+        self._tracer.detach()
+        self._tracer = self._detached if tracer is None else tracer
+        self._tracer.attach()
 
     def _as_arrays(self, tasks) -> TaskArrays:
         return tasks if isinstance(tasks, TaskArrays) else \
@@ -743,12 +738,11 @@ class ScanFlexAI:
             if self.dp:  # the DP runner always carries a [lanes, T] axis
                 ta = TaskArrays(*[np.asarray(f)[None] for f in ta])
         tr = self._tracer
-        with OFF if tr is None else tr.span("episode"):
-            with OFF if tr is None else tr.span("episode.upload"):
-                if tr is not None:
-                    tr.to_device(ta)
+        with tr.span("episode"):
+            with tr.span("episode.upload"):
+                tr.to_device(ta)
                 ta = jax.device_put(ta)
-            with OFF if tr is None else tr.span("episode.call"):
+            with tr.span("episode.call"):
                 if health is None:
                     out = self._train_fn(self.ts, ta)
                 else:
@@ -756,17 +750,15 @@ class ScanFlexAI:
                         self.ts, ta, health=jnp.asarray(health, jnp.float32))
                 jax.block_until_ready(out)
             self.ts, plat, recs, losses, upd = out
-            with OFF if tr is None else tr.span("episode.fetch"):
-                if tr is not None:
-                    tr.to_host((recs, losses, upd))
+            with tr.span("episode.fetch"):
+                tr.to_host((recs, losses, upd))
                 recs, losses, upd = jax.device_get((recs, losses, upd))
             upd = np.asarray(upd, bool)
             self.last_episode = (recs, losses, upd)
-            if tr is not None:
-                tr.count("episodes")
-                tr.count("train_steps", int(np.asarray(recs.valid).sum()))
-                tr.count("td_updates", int(upd.sum()))
-            with OFF if tr is None else tr.span("episode.summarize"):
+            tr.count("episodes")
+            tr.count("train_steps", int(np.asarray(recs.valid).sum()))
+            tr.count("td_updates", int(upd.sum()))
+            with tr.span("episode.summarize"):
                 return self._summarize_episode(plat, recs, losses, upd)
 
     def _summarize_episode(self, plat, recs, losses, upd) -> dict:
@@ -848,8 +840,7 @@ class ScanFlexAI:
                     for i in range(per_lane)]
                 history.append(self.train_episode(lane_routes))
             if ta_eval is not None and (ep + 1) % eval_every == 0:
-                with OFF if self._tracer is None else \
-                        self._tracer.span("eval"):
+                with self._tracer.span("eval"):
                     stms = self._eval_stms(ta_eval)
                 history[-1]["eval_stm"] = (
                     stms[0] if len(stms) == 1 else stms)

@@ -1,6 +1,7 @@
 """Serving launcher: batched wave serving of a smoke-config model, or —
-with ``--placement`` — FlexAI multi-vehicle placement serving on the
-(optionally sharded) device-resident scheduler.
+with ``--placement`` — FlexAI multi-vehicle placement serving through
+the QoS wave engine (``repro.serve.qos.QoSPlacementEngine``), FIFO
+admission by default, optionally sharded over every device.
 
     PYTHONPATH=src python -m repro.launch.serve --arch stablelm-1.6b --smoke \
         --requests 8 --max-new 16
@@ -9,18 +10,18 @@ with ``--placement`` — FlexAI multi-vehicle placement serving on the
     PYTHONPATH=src python -m repro.launch.serve --placement --shard \
         --routes 8 --route-km 0.03
 
-Deadline-aware QoS serving (``repro.serve.qos``): ``--qos edf`` admits
-waves earliest-effective-deadline-first with aging credit, preemption and
-shedding; ``--deadline-scale`` tightens/relaxes the Table-5 budgets:
+Routes arrive over a virtual timeline (``--arrival-gap``) with Table-5
+deadlines (``--deadline-scale`` tightens or relaxes them).  ``--qos edf``
+admits waves earliest-effective-deadline-first with aging credit,
+preemption and shedding:
 
     PYTHONPATH=src python -m repro.launch.serve --placement --qos edf \
         --routes 8 --route-km 0.01 --arrival-gap 0.02
 
-Production-serving extras (ISSUE 10): ``--continuous`` refills freed
-wave lanes at segment boundaries instead of draining, ``--measured-svc``
-replaces the virtual service clock with a measured per-bucket EMA, and
-``--shard`` now also shards plain (non-durable) QoS waves over the
-``("routes",)`` mesh — bit-exact against the single-device path.
+``--continuous`` refills freed wave lanes at segment boundaries instead
+of draining, ``--measured-svc`` replaces the virtual service clock with
+a measured per-bucket EMA, and ``--shard`` shards the waves' lanes over
+the ``("routes",)`` mesh — bit-exact against the single-device path.
 """
 from __future__ import annotations
 
@@ -34,7 +35,7 @@ import numpy as np
 from repro.compat import enable_compile_cache
 from repro.configs import ARCH_IDS, get_config, get_smoke_config
 from repro.models.api import model_api
-from repro.serve.engine import FlexAIPlacementService, Request, ServeEngine
+from repro.serve.engine import Request, ServeEngine
 from repro.sharding import unbox
 
 
@@ -47,9 +48,7 @@ def run_token_serving(args) -> int:
     params = unbox(api.init(jax.random.PRNGKey(0)))
     eng = ServeEngine(api, params, slots=args.slots, max_seq=args.max_seq,
                       temperature=args.temperature,
-                      qos=args.qos or "fifo",
-                      deadline_scale=args.deadline_scale
-                      if args.deadline_scale is not None else 1.0)
+                      qos=args.qos, deadline_scale=args.deadline_scale)
     rng = np.random.default_rng(0)
     for uid in range(args.requests):
         plen = int(rng.integers(3, 10))
@@ -91,11 +90,12 @@ def _route_params(args, i: int):
 
 
 def run_qos_placement_serving(args):
-    """Deadline-aware placement serving: routes arrive over a virtual
-    timeline and are admitted EDF (or bucket-FIFO) with Table-5-derived
-    deadlines, aging, preemption and shedding (see ``repro.serve.qos``).
-    Returns the drained engine, or None when the flags conflict (the
-    reason is printed).
+    """Placement serving, what every ``--placement`` run does: routes
+    arrive over a virtual timeline with Table-5-derived deadlines and are
+    admitted bucket-FIFO, or with ``--qos edf`` EDF with aging,
+    preemption and shedding (see ``repro.serve.qos``).  Returns the
+    drained engine, or None when the flags conflict (the reason is
+    printed).
 
     Durability flags (``repro.serve.durability``): ``--snapshot-dir`` /
     ``--snapshot-every`` write crash-recovery snapshots on a segment
@@ -141,9 +141,7 @@ def run_qos_placement_serving(args):
         if args.weights:
             agent.load_weights(args.weights)
         params, backlog_scale = agent.learner.eval_p, agent.cfg.backlog_scale
-    cfg = QoSConfig(policy=args.qos or "fifo",
-                    deadline_scale=args.deadline_scale
-                    if args.deadline_scale is not None else 1.0,
+    cfg = QoSConfig(policy=args.qos, deadline_scale=args.deadline_scale,
                     slots=args.slots, min_bucket=args.min_bucket,
                     stages=args.stages, continuous=args.continuous,
                     measured_svc=args.measured_svc)
@@ -200,11 +198,10 @@ def run_qos_placement_serving(args):
         eng.tracer = Tracer()
 
     if not args.resume:
-        gap = args.arrival_gap if args.arrival_gap is not None else 0.05
         t = 0.0
         for i in range(args.routes):
             eng.submit(build_task_queue(_route_params(args, i)), arrival=t)
-            t += gap
+            t += args.arrival_gap
     t0 = time.perf_counter()
     if durable and args.serve_waves:
         n = eng.serve_waves(args.serve_waves)
@@ -241,42 +238,6 @@ def run_qos_placement_serving(args):
     return eng
 
 
-def run_placement_serving(args) -> int:
-    """Each request is one vehicle's route; placements come from the
-    device-resident scan engine, sharded over all visible devices with
-    ``--shard`` (run under ``--xla_force_host_platform_device_count=N``
-    on CPU)."""
-    from repro.compat import make_mesh
-    from repro.core.environment import build_task_queue
-    from repro.core.flexai import FlexAIAgent, FlexAIConfig
-    from repro.core.hmai import HMAIPlatform
-
-    plat = HMAIPlatform(capacity_scale=args.rate_scale)
-    agent = FlexAIAgent(plat, FlexAIConfig(seed=args.seed))
-    if args.weights:
-        agent.load_weights(args.weights)
-
-    mesh = None
-    if args.shard:
-        n_dev = len(jax.devices())
-        mesh = make_mesh((n_dev,), ("routes",))
-        print(f"placement mesh: {n_dev} device(s) on axis 'routes'")
-    svc = FlexAIPlacementService(plat, agent.learner.eval_p,
-                                 min_bucket=args.min_bucket, mesh=mesh)
-
-    queues = [build_task_queue(_route_params(args, i))
-              for i in range(args.routes)]
-    n_tasks = sum(len(q) for q in queues)
-    t0 = time.perf_counter()
-    results = svc.place(queues)
-    dt = time.perf_counter() - t0
-    stm = float(np.mean([r["stm_rate"] for r in results]))
-    print(f"placed {len(queues)} routes / {n_tasks} tasks in {dt:.2f}s "
-          f"({n_tasks/dt:.0f} tasks/s, {svc.dispatches} dispatches, "
-          f"mean stm_rate {stm:.3f})")
-    return 0
-
-
 def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCH_IDS)
@@ -286,20 +247,18 @@ def parse_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--max-seq", type=int, default=64)
     ap.add_argument("--temperature", type=float, default=0.0)
-    # deadline-aware QoS (both serving modes); any of these explicitly set
-    # routes --placement through the QoS wave engine (None = unset)
-    ap.add_argument("--qos", choices=["fifo", "edf"], default=None,
-                    help="wave admission policy (edf = deadline-aware; "
-                         "default fifo)")
-    ap.add_argument("--deadline-scale", type=float, default=None,
-                    help="scales every derived deadline budget "
-                         "(default 1.0)")
-    ap.add_argument("--arrival-gap", type=float, default=None,
+    # deadline-aware QoS (both serving modes)
+    ap.add_argument("--qos", choices=["fifo", "edf"], default="fifo",
+                    help="wave admission policy (edf = deadline-aware)")
+    ap.add_argument("--deadline-scale", type=float, default=1.0,
+                    help="scales every derived deadline budget")
+    ap.add_argument("--arrival-gap", type=float, default=0.05,
                     help="virtual seconds between route arrivals "
-                         "(placement QoS mode; default 0.05)")
+                         "(placement)")
     # FlexAI placement serving
     ap.add_argument("--placement", action="store_true",
-                    help="serve FlexAI route placements instead of tokens")
+                    help="serve FlexAI route placements instead of tokens, "
+                         "through the QoS wave engine (FIFO unless --qos)")
     ap.add_argument("--shard", action="store_true",
                     help="shard the placement engine over all devices")
     ap.add_argument("--routes", type=int, default=8)
@@ -308,12 +267,12 @@ def parse_args(argv=None) -> argparse.Namespace:
     ap.add_argument("--min-bucket", type=int, default=64)
     ap.add_argument("--stages", type=int, default=1,
                     help="pipeline stages per wave (>1 serves stage-level "
-                         "placements via core.pipeline; QoS mode only, "
-                         "incompatible with durability flags)")
+                         "placements via core.pipeline; incompatible with "
+                         "durability flags)")
     ap.add_argument("--continuous", action="store_true",
                     help="continuous batching: refill freed wave lanes at "
-                         "segment boundaries instead of draining (QoS "
-                         "mode only, incompatible with durability flags)")
+                         "segment boundaries instead of draining "
+                         "(incompatible with durability flags)")
     ap.add_argument("--measured-svc", action="store_true",
                     help="advance the serving clock by measured segment "
                          "wall time (per-bucket EMA) instead of the "
@@ -349,9 +308,9 @@ def parse_args(argv=None) -> argparse.Namespace:
                     help="wall sleep per segment (widens the kill window "
                          "for the crash-recovery subprocess test)")
     ap.add_argument("--trace", action="store_true",
-                    help="QoS wave engine: attach a span tracer and print "
-                         "its summary at exit (per span: count, total and "
-                         "self ms; transfer counters), plus per-segment, "
+                    help="placement: attach a span tracer and print its "
+                         "summary at exit (per span: count, total and self "
+                         "ms; transfer counters), plus per-segment, "
                          "snapshot and fault progress lines")
     args = ap.parse_args(argv)
     if not args.placement and args.arch is None:
@@ -363,15 +322,7 @@ def main(argv=None) -> int:
     args = parse_args(argv)
     enable_compile_cache()
     if args.placement:
-        # any QoS- or durability-shaped flag (even an explicit default
-        # value) routes to the deadline-aware wave engine; the plain
-        # batch service has no timeline for them to act on
-        if (args.qos is not None or args.arrival_gap is not None
-                or args.deadline_scale is not None or args.stages > 1
-                or args.continuous or args.measured_svc or args.trace
-                or _durable_mode(args)):
-            return 0 if run_qos_placement_serving(args) is not None else 1
-        return run_placement_serving(args)
+        return 0 if run_qos_placement_serving(args) is not None else 1
     return run_token_serving(args)
 
 

@@ -55,12 +55,10 @@ import numpy as np
 
 from repro.core.flexai.dqn import DQNParams
 from repro.core.flexai.engine import _schedule_run_masked
-from repro.core.platform_jax import (PlatformSpec, PlatformState,
-                                     StepRecord, platform_init, stack_states)
-from repro.core.tasks import TaskArrays, pad_route_batch
-from repro.serve.qos import (COMPLETED, PREEMPTED, QoSConfig,
-                             QoSPlacementEngine, RouteRequest, Wave)
-from repro.serve.tracing import OFF
+from repro.core.platform_jax import PlatformSpec, PlatformState, StepRecord
+from repro.core.tasks import TaskArrays
+from repro.serve.qos import (QoSConfig, QoSPlacementEngine, RouteRequest,
+                             Wave)
 from repro.train import checkpoint as ckpt_lib
 from repro.train.fault_tolerance import (HeartbeatRecord, PreemptionGuard,
                                          StragglerDetector)
@@ -584,23 +582,10 @@ class DurableQoSEngine(QoSPlacementEngine):
         self._fire_due_faults()
         if self._stub or not self._use_masked:
             return super()._dispatch_segment(wave, seg)
-        alive = jnp.asarray(self.alive)
         fn = _masked_segment_fn(self.cur_spec, self.backlog_scale,
                                 mesh=self.mesh)
-        if self.mesh is not None:
-            pad = (-self.cfg.slots) % self.mesh.size
-            if pad:
-                seg = pad_route_batch(seg, self.mesh.size)
-                state = jax.tree_util.tree_map(
-                    lambda a, b: jnp.concatenate(
-                        [jnp.asarray(a), jnp.asarray(b)]),
-                    wave.state,
-                    stack_states([platform_init(self.spec.n)] * pad))
-                st, recs = fn(self.params, seg, state, alive)
-                trim = lambda a: a[: self.cfg.slots]  # noqa: E731
-                return (jax.tree_util.tree_map(trim, st),
-                        jax.tree_util.tree_map(trim, recs))
-        return fn(self.params, seg, wave.state, alive)
+        return self._call_lanes(fn, seg, wave.state,
+                                jnp.asarray(self.alive))
 
     def _charge_segment(self, wave: Wave, recs) -> None:
         cost = self.cfg.chunk * self.svc
@@ -667,8 +652,7 @@ class DurableQoSEngine(QoSPlacementEngine):
         # Deferring the device transfers to the writer thread measures
         # worse, not better: hundreds of background device_gets contend
         # with serving's own dispatches on the GIL and the jax runtime.
-        tr = self.tracer
-        with OFF if tr is None else tr.span("snapshot"):
+        with self._tracer.span("snapshot"):
             arrays, meta = pack_engine(self, inflight=inflight)
             self.saver.save(self.snapshots_written,
                             encode_snapshot(arrays, meta))
@@ -730,12 +714,7 @@ class DurableQoSEngine(QoSPlacementEngine):
         the restored state (a pure function of clock + queues, hence the
         same verdict the uninterrupted run reached) before serving on."""
         w, self._inflight = self._inflight, None
-        if w.progress < w.bucket and self._should_preempt(w):
-            w.preemptions += 1
-            self.preemption_count += 1
-            for r in w.requests:
-                r.status = PREEMPTED
-            self.preempted.append(w)
+        if w.progress < w.bucket and self._preempt_if_due(w):
             return
         self._run_wave(w)
 

@@ -8,7 +8,6 @@ update is in-place.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -87,109 +86,6 @@ class Request:
     def submit_order(self) -> int:
         # QoSPolicy sort-key protocol (ties inside one wave break on uid)
         return self.uid
-
-
-class FlexAIPlacementService:
-    """Multi-vehicle placement serving on the device-resident scheduler.
-
-    Each request is one vehicle's task queue (a route, or a camera-burst
-    window of it).  Queues are precompiled to ``TaskArrays``, right-padded
-    to power-of-two length buckets, stacked per bucket, and dispatched
-    through the vmapped greedy ``schedule_scan`` — one device call per
-    (bucket, batch-size) shape, compiled executables cached across calls.
-    This is the serving analogue of the engine's training batcher: the
-    per-frame Python loop never runs on the request path.
-    """
-
-    def __init__(self, platform, params, *, backlog_scale: float = 1.0,
-                 min_bucket: int = 64, mesh=None,
-                 tight_slack_s: "float | None" = None):
-        from repro.core.flexai.engine import (make_schedule_fn,
-                                              make_sharded_schedule_fn)
-        from repro.core.platform_jax import spec_from_platform
-        self.spec = spec_from_platform(platform)
-        self.params = params
-        self.backlog_scale = backlog_scale
-        self.min_bucket = min_bucket
-        self.tight_slack_s = tight_slack_s
-        self.shards = 1 if mesh is None else int(mesh.size)
-        if mesh is None:
-            self._batched_fn = make_schedule_fn(self.spec, backlog_scale,
-                                                batched=True)
-        else:
-            # multi-device serving: each bucket's lane batch is padded to
-            # a multiple of the mesh size and split across devices
-            self._batched_fn = make_sharded_schedule_fn(
-                self.spec, mesh, backlog_scale, axis=mesh.axis_names[0])
-        # tight-deadline lane: the single-route fused scan, dispatched
-        # immediately instead of waiting to co-batch with bucket peers
-        self._fused_fn = make_schedule_fn(self.spec, backlog_scale)
-        self.dispatches = 0
-        self.fused_dispatches = 0
-
-    def _bucket(self, n: int) -> int:
-        from repro.serve.qos import power_of_two_bucket
-        return power_of_two_bucket(n, self.min_bucket)
-
-    def place(self, queues: list, deadlines: "list | None" = None,
-              now: float = 0.0) -> list[dict]:
-        """Schedule every queue; returns one summary dict per queue with
-        ``placements`` trimmed to the queue's real length.
-
-        ``deadlines`` (absolute, same clock as ``now``) is the QoS seam:
-        when ``tight_slack_s`` is set, any request whose remaining slack
-        ``deadline - now`` is below it skips bucket co-batching and goes
-        straight through the single-route fused scan path — it pays the
-        solo dispatch instead of waiting for peers to amortize one.
-        Summaries carry ``path`` ("fused" or "batched") either way.
-        """
-        from repro.core.platform_jax import summarize
-        from repro.core.tasks import (TaskArrays, pad_route_batch,
-                                      pad_task_arrays, stack_task_arrays,
-                                      tasks_to_arrays)
-        arrays = [q if isinstance(q, TaskArrays) else tasks_to_arrays(q)
-                  for q in queues]
-        results: list = [None] * len(arrays)
-        tight: set = set()
-        if deadlines is not None and self.tight_slack_s is not None:
-            tight = {i for i, d in enumerate(deadlines)
-                     if d is not None and d - now < self.tight_slack_s}
-        for i in sorted(tight):
-            ta = pad_task_arrays(arrays[i], self._bucket(arrays[i].num_tasks))
-            final, recs = self._fused_fn(self.params, ta)
-            final, recs = jax.device_get((final, recs))
-            self.dispatches += 1
-            self.fused_dispatches += 1
-            summ = summarize(self.spec, final, recs)
-            summ["placements"] = recs.action[: arrays[i].num_tasks]
-            summ["bucket"] = ta.num_tasks
-            summ["path"] = "fused"
-            results[i] = summ
-        by_bucket: dict = {}
-        for i, ta in enumerate(arrays):
-            if i in tight:
-                continue
-            by_bucket.setdefault(self._bucket(ta.num_tasks), []).append(i)
-        for bucket, idxs in sorted(by_bucket.items()):
-            batch = stack_task_arrays(
-                [pad_task_arrays(arrays[i], bucket) for i in idxs])
-            if self.shards > 1:
-                batch = pad_route_batch(batch, self.shards)
-            out = self._batched_fn(self.params, batch)
-            # one device->host transfer per bucket, then NumPy slicing —
-            # per-lane device gathers would issue hundreds of tiny
-            # blocking transfers on the serving hot path
-            finals, recs = jax.device_get(out)
-            self.dispatches += 1
-            for lane, i in enumerate(idxs):
-                take = jax.tree_util.tree_map(lambda a, l=lane: a[l],
-                                              (finals, recs))
-                summ = summarize(self.spec, take[0], take[1])
-                summ["placements"] = take[1].action[: arrays[i].num_tasks]
-                summ["bucket"] = bucket
-                summ["path"] = "batched"
-                results[i] = summ
-        return results
 
 
 class ServeEngine:

@@ -62,10 +62,13 @@ Two production paths land on top (ISSUE 10):
   once its remaining service can no longer meet its deadline) is
   refilled at the next segment boundary from the backlog, JetStream
   prefill-insert style.  Refill only admits the request global admission
-  would pick next (and only if its bucket matches the in-flight wave),
-  so EDF ordering and the aging starvation bound survive; the refilled
-  lane's ``PlatformState`` row is reinitialized, and the wave remains a
-  preemptible checkpointed unit with per-lane cursors.
+  would pick next (``_admission_head``, the rule ``_select_wave`` uses,
+  and only if its bucket matches the in-flight wave), so EDF ordering
+  and the aging starvation bound survive; the refilled lane's
+  ``PlatformState`` row is reinitialized, and the wave remains a
+  preemptible checkpointed unit with per-lane offsets.  Drain and
+  continuous waves run the one segment loop, ``_run_wave``: a drain
+  wave's lanes all reach their end on its last segment.
 
 Tracing: ``engine.tracer = serve.tracing.Tracer()`` records the loop's
 spans — ``admit`` (children ``admit.pack_tasks``, ``admit.init_state``),
@@ -76,7 +79,8 @@ per request — with its transfer counters, ``waves_admitted``,
 ``fresh_state_reuses`` (fresh waves that took the engine's constant
 checkpoint in ``admit.init_state``; resumed waves and continuous refills
 do not count) and the requests' ``t_submit`` / ``t_admit`` / ``t_done``.
-``None``, the default, costs one test per site.
+``None``, the default, leaves ``serve.tracing.DETACHED`` in place, whose
+calls do nothing.
 """
 from __future__ import annotations
 
@@ -97,7 +101,7 @@ from repro.core.tasks import (TaskArrays, invalid_task_arrays,
                               stack_task_arrays, tasks_to_arrays)
 from repro.serve.policy import (QoSPolicy, effective_deadline,
                                 power_of_two_bucket)
-from repro.serve.tracing import OFF
+from repro.serve.tracing import DETACHED
 
 __all__ = [
     "QoSConfig", "QoSPlacementEngine", "RouteRequest", "Wave", "QoSPolicy",
@@ -265,11 +269,19 @@ class RouteRequest:
 class Wave:
     """An admitted (and possibly checkpointed) lockstep wave.
 
+    Each lane holds one occupant (or none) and the offset its occupant
+    has reached; ``recs`` holds the per-segment records some occupant
+    still needs.  A lane refilled at ``progress`` p holds its request's
+    tasks rotated by p mod ``length``, so every lane's next tasks sit in
+    the same columns and one slice per leaf builds each segment; a free
+    lane holds invalid rows.  A wave built from a snapshot takes its lane
+    offsets from ``progress``.
+
     Pipeline waves (``cfg.stages > 1``) carry the flat wavefront stream
     in ``batch`` ([slots, flat_len]) plus the shared stage sequence and
     the per-lane ring of upstream finish times — ``(state, ring)`` is the
     preemption checkpoint there."""
-    requests: list           # lane-aligned RouteRequests (may be < slots)
+    requests: list           # occupants in lane order (may be < slots)
     batch: TaskArrays        # [slots, bucket] (or [slots, flat_len])
     state: PlatformState     # [slots, ...] — THE preemption checkpoint
     bucket: int
@@ -280,12 +292,19 @@ class Wave:
     s_seq: Optional[np.ndarray] = None   # [flat_len] stage per flat slot
     ring: Optional[jax.Array] = None     # [slots, S] checkpoint half 2
     flat_len: Optional[int] = None       # padded wavefront length
-    # continuous batching (cfg.continuous): per-lane occupancy — the
-    # checkpoint widens to (state, lane cursors) but stays on the Wave,
-    # so preempt/resume is unchanged
-    lane_requests: Optional[list] = None  # [slots] RouteRequest | None
-    lane_progress: Optional[list] = None  # [slots] slots served per lane
-    lane_recs: Optional[list] = None      # [slots] per-lane record chunks
+    lane_requests: list = dataclasses.field(init=False)  # [slots] | None
+    lane_offsets: list = dataclasses.field(init=False)   # [slots] tasks
+
+    def __post_init__(self):
+        slots = self.batch.valid.shape[0]
+        self.lane_requests = (list(self.requests)
+                              + [None] * (slots - len(self.requests)))
+        self.lane_offsets = [self.progress] * slots
+
+    @property
+    def length(self) -> int:
+        """Task slots a lane's occupant is served for."""
+        return self.flat_len if self.flat_len is not None else self.bucket
 
     def min_deadline(self, aging_credit: float) -> float:
         return min(effective_deadline(r.deadline, self.waves_waited,
@@ -409,20 +428,18 @@ class QoSPlacementEngine:
         self.dispatches = 0
         self.preemption_count = 0
         self.refills = 0
-        self._tracer = None
+        self._tracer = DETACHED
 
     @property
     def tracer(self):
         """The attached ``serve.tracing.Tracer``, or None (off)."""
-        return self._tracer
+        return None if self._tracer is DETACHED else self._tracer
 
     @tracer.setter
     def tracer(self, tracer) -> None:
-        if self._tracer is not None:
-            self._tracer.detach()
-        self._tracer = tracer
-        if tracer is not None:
-            tracer.attach()
+        self._tracer.detach()
+        self._tracer = DETACHED if tracer is None else tracer
+        self._tracer.attach()
 
     # ------------------------------------------------------------------
     # submission
@@ -490,7 +507,7 @@ class QoSPlacementEngine:
                            n_tasks=n, arrival=float(arrival),
                            deadline=float(deadline), bucket=bucket,
                            submit_order=self._order)
-        if self._tracer is not None:
+        if self.tracer is not None:
             req.t_submit = time.perf_counter_ns() * 1e-9
         self._order += 1
         if req.arrival <= self.now:
@@ -546,13 +563,11 @@ class QoSPlacementEngine:
         wave_reqs = peers[: self.cfg.slots]
         taken = {r.uid for r in wave_reqs}
         self.backlog = [r for r in self.backlog if r.uid not in taken]
-        self.qpolicy.age(self.backlog)
-        self.qpolicy.age(self.preempted)
         for r in wave_reqs:
             r.status = RUNNING
         tr = self._tracer
         s_seq = flat_len = None
-        with OFF if tr is None else tr.span("admit.pack_tasks"):
+        with tr.span("admit.pack_tasks"):
             rows = [r.tasks for r in wave_reqs]
             rows += [invalid_task_arrays(head.bucket)
                      for _ in range(self.cfg.slots - len(rows))]
@@ -560,10 +575,9 @@ class QoSPlacementEngine:
             if self.plan is not None:
                 batch, s_seq, flat_len = self._flatten_batch(batch,
                                                              head.bucket)
-        with OFF if tr is None else tr.span("admit.init_state"):
+        with tr.span("admit.init_state"):
             state, ring = self._fresh_state, self._fresh_ring
-            if tr is not None:
-                tr.count("fresh_state_reuses")
+            tr.count("fresh_state_reuses")
         self._admitted(wave_reqs)
         # the wave inherits its members' earned aging credit, so a
         # long-aged request that gets preempted right after admission does
@@ -594,13 +608,17 @@ class QoSPlacementEngine:
         return stack_task_arrays(lanes), s_seq, flat_len
 
     def _admitted(self, reqs: list) -> None:
-        """Log one admission round; with a tracer, stamp the first
-        admission of each request and record its ``queued`` span."""
+        """Log one admission round, which everyone still waiting is
+        passed over by and ages one wave for; with a tracer, stamp the
+        first admission of each request and record its ``queued``
+        span."""
         self.wave_log.append([r.uid for r in reqs])
+        self.qpolicy.age(self.backlog)
+        self.qpolicy.age(self.preempted)
         tr = self._tracer
-        if tr is None:
-            return
         tr.count("waves_admitted")
+        if self.tracer is None:
+            return
         wid = len(self.wave_log) - 1
         t = time.perf_counter_ns()
         for r in reqs:
@@ -612,13 +630,10 @@ class QoSPlacementEngine:
 
     def _next_wave(self) -> Optional[Wave]:
         """Admit the next wave (None when nothing is left to serve)."""
-        tr = self._tracer
-        if tr is None:
-            return self._select_wave()
         # the round about to be logged, so the children carry it too
-        with tr.span("admit", wave=len(self.wave_log)) as sp:
+        with self._tracer.span("admit", wave=len(self.wave_log)) as sp:
             wave = self._select_wave()
-            if wave is None:
+            if wave is None and sp is not None:
                 sp.wave = None
         return wave
 
@@ -637,31 +652,33 @@ class QoSPlacementEngine:
             if not self.pending:  # everything left was shed
                 return None
             # an all-infeasible arrival group was shed; advance to the next
-        if self.cfg.policy == "fifo":
-            if self.preempted:      # only reachable via external injection:
-                # _should_preempt gates on "edf", but resume consistently
-                return self._resume(self.preempted[0])
-            head = min(self.backlog, key=lambda r: r.submit_order)
-            return self._pack_wave(head)
-        # EDF: fresh requests and preempted waves compete on effective
-        # deadline; a resumed wave re-enters at its checkpoint
-        best_req = min(self.backlog, default=None,
-                       key=self.qpolicy.request_key)
-        best_wave = min(self.preempted, default=None,
-                        key=lambda w: w.min_deadline(self.cfg.aging_credit))
-        if best_wave is not None and (
-                best_req is None
-                or best_wave.min_deadline(self.cfg.aging_credit)
-                <= self._eff_deadline(best_req)):
-            return self._resume(best_wave)
-        return self._pack_wave(best_req)
+        head = self._admission_head()
+        if isinstance(head, Wave):
+            return self._resume(head)
+        return self._pack_wave(head)
+
+    def _admission_head(self) -> "RouteRequest | Wave | None":
+        """What global admission runs next: a backlog request or a
+        checkpointed wave (None when both are empty).  FIFO takes the
+        lowest ``submit_order``, after any checkpointed wave (FIFO never
+        preempts, so only an outside caller puts one there).  EDF
+        compares effective deadlines; a wave wins a tie and re-enters at
+        its checkpoint."""
+        if not self.qpolicy.is_edf and self.preempted:
+            return self.preempted[0]
+        req = min(self.backlog, default=None, key=self.qpolicy.request_key)
+        wave = min(self.preempted, default=None,
+                   key=lambda w: w.min_deadline(self.cfg.aging_credit))
+        if wave is not None and (
+                req is None or wave.min_deadline(self.cfg.aging_credit)
+                <= self._eff_deadline(req)):
+            return wave
+        return req
 
     def _resume(self, wave: Wave) -> Wave:
-        """Re-admit a preempted wave at its checkpoint: same aging and
-        wave_log bookkeeping as a fresh admission."""
+        """Re-admit a preempted wave at its checkpoint, an admission
+        round like a fresh one."""
         self.preempted.remove(wave)
-        self.qpolicy.age(self.backlog)
-        self.qpolicy.age(self.preempted)
         for r in wave.requests:
             r.status = RUNNING
         self._admitted(wave.requests)
@@ -697,30 +714,29 @@ class QoSPlacementEngine:
         """Serve one chunk: returns ``(new_state, records)``; a pipeline
         segment also advances the wave's ring.  The durability layer
         swaps in fault-masked / mesh-sharded executors here without
-        touching the wave loop.  With a mesh the lane axis is padded to
-        the mesh size (invalid rows + fresh states) and trimmed back —
-        per-lane scans are independent, so sharding is
-        placement-neutral."""
+        touching the wave loop."""
         if self.plan is not None:
             state, wave.ring, recs = self._seg_fn(
                 self.params, seg, self._stage_slice(wave), wave.state,
                 wave.ring)
             return state, recs
-        if self.mesh is not None:
-            pad = (-self.cfg.slots) % self.mesh.size
-            if pad:
-                import jax.numpy as jnp
-                seg = pad_route_batch(seg, self.mesh.size)
-                state = jax.tree_util.tree_map(
-                    lambda a, b: jnp.concatenate(
-                        [jnp.asarray(a), jnp.asarray(b)]),
-                    wave.state,
-                    stack_states([platform_init(self.spec.n)] * pad))
-                st, recs = self._seg_fn(self.params, seg, state)
-                trim = lambda a: a[: self.cfg.slots]  # noqa: E731
-                return (jax.tree_util.tree_map(trim, st),
-                        jax.tree_util.tree_map(trim, recs))
-        return self._seg_fn(self.params, seg, wave.state)
+        return self._call_lanes(self._seg_fn, seg, wave.state)
+
+    def _call_lanes(self, fn, seg: TaskArrays, state, *extra):
+        """``fn(params, seg, state, *extra)``.  With a mesh the lane axis
+        is padded to the mesh size (invalid rows + fresh states) and
+        trimmed back — per-lane scans are independent, so sharding is
+        placement-neutral."""
+        pad = 0 if self.mesh is None else (-self.cfg.slots) % self.mesh.size
+        if not pad:
+            return fn(self.params, seg, state, *extra)
+        import jax.numpy as jnp
+        state = jax.tree_util.tree_map(
+            lambda a, b: jnp.concatenate([jnp.asarray(a), jnp.asarray(b)]),
+            state, stack_states([platform_init(self.spec.n)] * pad))
+        out = fn(self.params, pad_route_batch(seg, self.mesh.size), state,
+                 *extra)
+        return jax.tree_util.tree_map(lambda a: a[: self.cfg.slots], out)
 
     def _timed_dispatch(self, wave: Wave, seg: TaskArrays):
         """Dispatch one segment (the ``segment.call`` span), measuring
@@ -729,11 +745,10 @@ class QoSPlacementEngine:
         what ``_charge_segment`` advances the clock by for this
         segment."""
         tr = self._tracer
-        with OFF if tr is None else tr.span("segment.call"):
-            if tr is not None:
-                tr.to_device(self.params, seg, wave.state, *(
-                    () if self.plan is None
-                    else (self._stage_slice(wave), wave.ring)))
+        with tr.span("segment.call"):
+            tr.to_device(self.params, seg, wave.state, *(
+                () if self.plan is None
+                else (self._stage_slice(wave), wave.ring)))
             if not self.cfg.measured_svc:
                 return self._dispatch_segment(wave, seg)
             t0 = time.perf_counter()
@@ -775,69 +790,103 @@ class QoSPlacementEngine:
     # --------------------------------------------------------------------
 
     def _run_wave(self, wave: Wave) -> None:
-        if self.cfg.continuous and self.plan is None:
-            return self._run_wave_continuous(wave)
-        chunk = self.cfg.chunk
-        total = wave.flat_len if wave.flat_len is not None else wave.bucket
-        while wave.progress < total:
-            tr = self._tracer
-            with (OFF if tr is None
-                  else tr.span("segment", wave=len(self.wave_log) - 1)):
-                p = wave.progress
-                with OFF if tr is None else tr.span("segment.slice"):
-                    seg = jax.tree_util.tree_map(
-                        lambda a: a[:, p: p + chunk], wave.batch)
-                state, recs = self._timed_dispatch(wave, seg)
-                # the records travel to the host behind the device's
-                # compute while the host dispatches on; the drain reads
-                # buffers that have landed
-                early = _start_host_copy(recs)
-                if tr is not None:
-                    tr.to_host(recs)
-                    tr.count("d2h_early", early)
-                self.dispatches += 1
-                wave.state = state
-                wave.recs.append(recs)
-                wave.progress += chunk
-                self._charge_segment(wave, recs)
-                self._promote_arrivals()
-                with OFF if tr is None else tr.span("hook"):
-                    self._after_segment(wave)
+        """The segment loop of every wave, drain and continuous.  Each
+        pass serves one segment, completes the lanes that reached their
+        end and, under ``cfg.continuous``, sheds overrun lanes and
+        refills free ones; the wave leaves when no lane is occupied or
+        when it is preempted.  A drain wave's lanes all end on its last
+        segment; a wave restored after that segment completes without
+        serving another."""
+        done = self._lanes_at_end(wave)
+        while True:
+            if not done:
+                self._serve_segment(wave)
                 if self._halt:
                     return  # durability stop: the wave was snapshotted
-                if wave.progress < total and self._should_preempt(wave):
-                    wave.preemptions += 1
-                    self.preemption_count += 1
-                    for r in wave.requests:
-                        r.status = PREEMPTED
-                    self.preempted.append(wave)
-                    return
-        self._drain_wave(wave)
+                done = self._lanes_at_end(wave)
+            if done:
+                self._complete_lanes(wave, done)
+                done = []
+            if self.cfg.continuous:
+                self._shed_overrun_lanes(wave)
+                self._refill(wave)
+                # keep only the record segments an occupant still needs
+                need = max((o for o, r in zip(wave.lane_offsets,
+                                              wave.lane_requests)
+                            if r is not None), default=0)
+                del wave.recs[: len(wave.recs) - need // self.cfg.chunk]
+            if not wave.requests or self._preempt_if_due(wave):
+                return
 
-    def _drain_wave(self, wave: Wave) -> None:
-        """Wave drained: bring its records and final state to the host;
-        every live lane completes at the current clock.  The records'
-        copies were started at dispatch (``_run_wave``), where they are
-        counted."""
+    def _preempt_if_due(self, wave: Wave) -> bool:
+        """Checkpoint the wave into ``preempted`` if a waiter is due."""
+        if not self._should_preempt(wave):
+            return False
+        wave.preemptions += 1
+        self.preemption_count += 1
+        for r in wave.requests:
+            r.status = PREEMPTED
+        self.preempted.append(wave)
+        return True
+
+    @staticmethod
+    def _lanes_at_end(wave: Wave) -> list:
+        return [lane for lane, r in enumerate(wave.lane_requests)
+                if r is not None and wave.lane_offsets[lane] >= wave.length]
+
+    def _serve_segment(self, wave: Wave) -> None:
+        """Dispatch the wave's next ``chunk`` columns and advance the
+        wave, the clock and the arrivals; then the ``_after_segment``
+        hook."""
+        chunk = self.cfg.chunk
         tr = self._tracer
-        with (OFF if tr is None
-              else tr.span("drain", wave=len(self.wave_log) - 1)):
-            with OFF if tr is None else tr.span("drain.records"):
+        with tr.span("segment", wave=len(self.wave_log) - 1):
+            p = wave.progress % wave.length
+            with tr.span("segment.slice"):
+                seg = jax.tree_util.tree_map(
+                    lambda a: a[:, p: p + chunk], wave.batch)
+            state, recs = self._timed_dispatch(wave, seg)
+            # the records travel to the host behind the device's compute
+            # while the host dispatches on; the drain reads buffers that
+            # have landed
+            early = _start_host_copy(recs)
+            tr.to_host(recs)
+            tr.count("d2h_early", early)
+            self.dispatches += 1
+            wave.state = state
+            wave.recs.append(recs)
+            wave.progress += chunk
+            wave.lane_offsets = [o + chunk for o in wave.lane_offsets]
+            self._charge_segment(wave, recs)
+            self._promote_arrivals()
+            with tr.span("hook"):
+                self._after_segment(wave)
+
+    def _complete_lanes(self, wave: Wave, lanes: list) -> None:
+        """Lanes that reached their end: bring the records they need and
+        the wave's state to the host, one ``device_get`` each, and
+        complete every lane at the current clock, in lane order.  The
+        records' copies were started at dispatch (``_serve_segment``),
+        where they are counted."""
+        tr = self._tracer
+        with tr.span("drain", wave=len(self.wave_log) - 1):
+            with tr.span("drain.records"):
+                segs = wave.recs[len(wave.recs)
+                                 - wave.length // self.cfg.chunk:]
                 recs = jax.tree_util.tree_map(
                     lambda *xs: np.concatenate(xs, axis=1),
-                    *jax.device_get(wave.recs))
-            with OFF if tr is None else tr.span("drain.state"):
-                if tr is not None:
-                    tr.to_host(wave.state)
+                    *jax.device_get(segs))
+            with tr.span("drain.state"):
+                tr.to_host(wave.state)
                 final = jax.device_get(wave.state)
             order = None
             if self.plan is not None:
                 from repro.core.pipeline import _record_order
                 order = np.asarray(_record_order(wave.bucket,
                                                  self.cfg.stages))
-            for lane, req in enumerate(wave.requests):
-                with OFF if tr is None else tr.span("drain.summarize",
-                                                    uid=req.uid):
+            for lane in lanes:
+                req = wave.lane_requests[lane]
+                with tr.span("drain.summarize", uid=req.uid):
                     lane_final = jax.tree_util.tree_map(lambda a: a[lane],
                                                         final)
                     lane_recs = jax.tree_util.tree_map(lambda a: a[lane],
@@ -857,6 +906,17 @@ class QoSPlacementEngine:
                         lane_recs.action)[: req.n_tasks]
                     summ["bucket"] = wave.bucket
                 self._finish(req, summ, lane_final, lane_recs)
+                self._vacate(wave, lane)
+
+    def _vacate(self, wave: Wave, lane: int) -> None:
+        """Free a lane: its occupant leaves the wave and its rows turn
+        invalid, so the scan passes the lane through until a refill."""
+        req = wave.lane_requests[lane]
+        wave.requests = [r for r in wave.requests if r is not req]
+        wave.lane_requests[lane] = None
+        wave.lane_offsets[lane] = 0
+        for a, b in zip(wave.batch, invalid_task_arrays(wave.length)):
+            a[lane] = b
 
     def _finish(self, req: RouteRequest, summ: dict, lane_final,
                 lane_recs) -> None:
@@ -866,108 +926,13 @@ class QoSPlacementEngine:
         req.status = COMPLETED
         req.finish = self.now
         req.slack = req.deadline - self.now
-        tr = self._tracer
-        if tr is not None:
+        if self.tracer is not None:
             req.t_done = time.perf_counter_ns() * 1e-9
-        with OFF if tr is None else tr.span("hook", uid=req.uid):
+        with self._tracer.span("hook", uid=req.uid):
             self._on_complete(req, lane_final, lane_recs)
         self.completed.append(req)
 
     # ---- continuous batching (cfg.continuous) --------------------------
-
-    def _run_wave_continuous(self, wave: Wave) -> None:
-        """Continuous-batching wave loop (JetStream prefill-insert
-        style): lanes carry independent cursors, and at every segment
-        boundary a freed lane — completed, or shed mid-flight once its
-        remaining service cannot meet its deadline — is refilled from
-        the backlog with a reinitialized ``PlatformState`` row.  The
-        wave stays a preemptible checkpointed unit: ``(state, lane
-        cursors)`` lives on the Wave, so preempt/resume re-enters here
-        unchanged."""
-        chunk, slots = self.cfg.chunk, self.cfg.slots
-        if wave.lane_requests is None:
-            wave.lane_requests = (list(wave.requests)
-                                  + [None] * (slots - len(wave.requests)))
-            wave.lane_progress = [0] * slots
-            wave.lane_recs = [[] for _ in range(slots)]
-        idle_row = invalid_task_arrays(chunk)
-        while True:
-            tr = self._tracer
-            with (OFF if tr is None
-                  else tr.span("segment", wave=len(self.wave_log) - 1)):
-                with OFF if tr is None else tr.span("segment.slice"):
-                    rows = []
-                    for lane in range(slots):
-                        r = wave.lane_requests[lane]
-                        if r is None:
-                            rows.append(idle_row)
-                        else:
-                            p = wave.lane_progress[lane]
-                            rows.append(jax.tree_util.tree_map(
-                                lambda a: a[p: p + chunk], r.tasks))
-                    seg = stack_task_arrays(rows)
-                state, recs = self._timed_dispatch(wave, seg)
-                self.dispatches += 1
-                wave.state = state
-                for lane in range(slots):
-                    if wave.lane_requests[lane] is not None:
-                        wave.lane_recs[lane].append(jax.tree_util.tree_map(
-                            lambda a: a[lane], recs))
-                        wave.lane_progress[lane] += chunk
-                wave.progress += chunk
-                self._charge_segment(wave, recs)
-                self._promote_arrivals()
-                with OFF if tr is None else tr.span("hook"):
-                    self._after_segment(wave)
-            if self._halt:
-                wave.requests = [r for r in wave.lane_requests
-                                 if r is not None]
-                return
-            for lane in range(slots):
-                r = wave.lane_requests[lane]
-                if (r is not None
-                        and wave.lane_progress[lane] >= wave.bucket):
-                    self._complete_lane(wave, lane)
-            self._shed_overrun_lanes(wave)
-            self._refill(wave)
-            wave.requests = [r for r in wave.lane_requests if r is not None]
-            if not wave.requests:
-                return
-            if self._should_preempt(wave):
-                wave.preemptions += 1
-                self.preemption_count += 1
-                for r in wave.requests:
-                    r.status = PREEMPTED
-                self.preempted.append(wave)
-                return
-
-    def _complete_lane(self, wave: Wave, lane: int) -> None:
-        """One lane reached its bucket: summarize exactly like a drained
-        wave's lane and free the slot for refill."""
-        r = wave.lane_requests[lane]
-        tr = self._tracer
-        with (OFF if tr is None
-              else tr.span("drain", wave=len(self.wave_log) - 1)):
-            with OFF if tr is None else tr.span("drain.records"):
-                if tr is not None:
-                    tr.to_host(wave.lane_recs[lane])
-                lane_recs = jax.tree_util.tree_map(
-                    lambda *xs: np.concatenate([np.asarray(x) for x in xs]),
-                    *wave.lane_recs[lane])
-            with OFF if tr is None else tr.span("drain.state"):
-                if tr is not None:
-                    tr.to_host(wave.state)
-                lane_final = jax.tree_util.tree_map(
-                    lambda a: a[lane], jax.device_get(wave.state))
-            with OFF if tr is None else tr.span("drain.summarize",
-                                                uid=r.uid):
-                summ = summarize(self.spec, lane_final, lane_recs)
-                summ["placements"] = np.asarray(lane_recs.action)[: r.n_tasks]
-                summ["bucket"] = wave.bucket
-            self._finish(r, summ, lane_final, lane_recs)
-        wave.lane_requests[lane] = None
-        wave.lane_progress[lane] = 0
-        wave.lane_recs[lane] = []
 
     def _shed_overrun_lanes(self, wave: Wave) -> None:
         """Mid-flight shed: a lane whose *remaining* service can no
@@ -980,78 +945,52 @@ class QoSPlacementEngine:
         for lane, r in enumerate(wave.lane_requests):
             if r is None:
                 continue
-            need = (wave.bucket - wave.lane_progress[lane]) * per_slot
+            need = (wave.bucket - wave.lane_offsets[lane]) * per_slot
             if self.qpolicy.should_shed(self.now, need, r.deadline):
                 self._shed_request(r, "overrun", need)
-                wave.lane_requests[lane] = None
-                wave.lane_progress[lane] = 0
-                wave.lane_recs[lane] = []
-
-    def _refill_head(self, wave: Wave) -> Optional[RouteRequest]:
-        """The request global admission would run next, or None if a
-        checkpointed wave (or nothing) should go first — refill must not
-        overtake the cross-bucket EDF/FIFO order, or aging's starvation
-        bound dies."""
-        if not self.backlog:
-            return None
-        if not self.qpolicy.is_edf:
-            if self.preempted:
-                return None
-            return min(self.backlog, key=lambda r: r.submit_order)
-        best_req = min(self.backlog, key=self.qpolicy.request_key)
-        best_wave = min(self.preempted, default=None,
-                        key=lambda w: w.min_deadline(self.cfg.aging_credit))
-        if best_wave is not None and (
-                best_wave.min_deadline(self.cfg.aging_credit)
-                <= self._eff_deadline(best_req)):
-            return None
-        return best_req
+                self._vacate(wave, lane)
 
     def _refill(self, wave: Wave) -> None:
         """Admit backlog into freed lanes at a segment boundary.  Only
-        the global admission head is eligible, and only while it shares
-        the wave's bucket; a refill round that admits anyone counts as
-        an admission round for aging (everyone passed over earns a
-        wave of credit, same as ``_pack_wave``)."""
-        free = [lane for lane in range(self.cfg.slots)
-                if wave.lane_requests[lane] is None]
+        the global admission head (``_admission_head``) is eligible, and
+        only while it shares the wave's bucket; a refill round that
+        admits anyone is an admission round (``_admitted``)."""
+        free = [lane for lane, r in enumerate(wave.lane_requests)
+                if r is None]
         if not free:
             return
-        tr = self._tracer
-        with (OFF if tr is None
-              else tr.span("admit", wave=len(self.wave_log))) as sp:
-            admitted = self._refill_lanes(wave, free)
-            if tr is not None and not admitted:
-                sp.wave = None
-
-    def _refill_lanes(self, wave: Wave, free: list) -> list:
-        if self.qpolicy.is_edf and self.cfg.shed:
-            self._shed_infeasible()
         import jax.numpy as jnp
         tr = self._tracer
-        admitted = []
-        for lane in free:
-            head = self._refill_head(wave)
-            if head is None or head.bucket != wave.bucket:
-                break
-            self.backlog.remove(head)
-            head.status = RUNNING
-            wave.lane_requests[lane] = head
-            wave.lane_progress[lane] = 0
-            wave.lane_recs[lane] = []
-            with OFF if tr is None else tr.span("admit.init_state"):
-                wave.state = jax.tree_util.tree_map(
-                    lambda a, b: jnp.asarray(a).at[lane].set(b),
-                    wave.state, platform_init(self.spec.n))
-            admitted.append(head)
-        if admitted:
+        with tr.span("admit", wave=len(self.wave_log)) as sp:
+            if self.qpolicy.is_edf and self.cfg.shed:
+                self._shed_infeasible()
+            shift = wave.progress % wave.length
+            admitted = []
+            for lane in free:
+                head = self._admission_head()
+                if not isinstance(head, RouteRequest) \
+                        or head.bucket != wave.bucket:
+                    break
+                self.backlog.remove(head)
+                head.status = RUNNING
+                wave.lane_requests[lane] = head
+                wave.lane_offsets[lane] = 0
+                for a, b in zip(wave.batch, head.tasks):
+                    a[lane] = np.roll(b, shift)
+                with tr.span("admit.init_state"):
+                    wave.state = jax.tree_util.tree_map(
+                        lambda a, b: jnp.asarray(a).at[lane].set(b),
+                        wave.state, platform_init(self.spec.n))
+                admitted.append(head)
+            if not admitted:
+                if sp is not None:
+                    sp.wave = None
+                return
+            wave.requests = [r for r in wave.lane_requests if r is not None]
             self.refills += len(admitted)
             self._admitted(admitted)
-            self.qpolicy.age(self.backlog)
-            self.qpolicy.age(self.preempted)
             wave.waves_waited = max(
                 [wave.waves_waited] + [r.waves_waited for r in admitted])
-        return admitted
 
     def run_until_done(self, max_waves: int = 100_000) -> None:
         for _ in range(max_waves):

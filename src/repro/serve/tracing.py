@@ -21,9 +21,9 @@ A :class:`Tracer` attached to a ``QoSPlacementEngine`` or a
 
 The counting rule: ``d2h_transfers`` / ``d2h_bytes`` count the
 ``jax.Array`` leaves the engine brings to the host, one transfer per
-leaf: a drained wave's records where their copy starts, at dispatch
-(``d2h_early`` counts these early starts alone), its state and a
-continuous lane's records at the drain; ``h2d_transfers`` /
+leaf: a segment's records where their copy starts, at dispatch
+(``d2h_early`` counts these early starts alone), and the wave's state
+once per drain, when lanes complete; ``h2d_transfers`` /
 ``h2d_bytes`` count the ``np.ndarray`` leaves handed to a jitted
 segment call (params, task slice, state and, for pipeline waves, the
 stage slice and ring), each uploaded once by the call;
@@ -31,7 +31,10 @@ stage slice and ring), each uploaded once by the call;
 ``fresh_state_reuses`` counts the fresh waves that took the engine's
 constant initial checkpoint (a resumed wave keeps its own, and a
 continuous refill resets one lane), so in drain mode without
-preemptions it equals ``waves_admitted``.
+preemptions it equals ``waves_admitted``.  Lanes that reach their end
+at one segment boundary drain together: in drain mode a wave drains
+once, so ``d2h_transfers`` is 10 record leaves a segment plus 11 state
+leaves a wave.
 In the trainer the same rule counts an episode's task arrays uploaded
 (``episode.upload``) and its records, losses and update mask brought to
 the host (``episode.fetch``); ``episodes`` counts fused episodes,
@@ -42,8 +45,8 @@ While a tracer is attached to an engine, every garbage collection of
 the process is recorded as a ``gc`` span, whose parent is the span open
 when it started.
 
-Off, the engine pays one ``is None`` test per site: no clock read, no
-annotation object, no allocation.
+While none is attached an engine holds :data:`DETACHED`, whose calls
+do nothing: no clock read, no annotation object, no allocation.
 """
 from __future__ import annotations
 
@@ -54,10 +57,41 @@ import time
 import jax
 import numpy as np
 
-__all__ = ["Tracer", "Span", "OFF"]
+__all__ = ["Tracer", "Span", "DETACHED"]
 
 # the context every span site enters when no tracer is attached
 OFF = contextlib.nullcontext()
+
+
+class _Detached:
+    """The tracer an engine holds while none is attached: every span is
+    the shared ``OFF`` context, every count a no-op."""
+    __slots__ = ()
+
+    def span(self, name: str, wave=None, uid=None):
+        return OFF
+
+    def record(self, name: str, start_ns: int, end_ns: int, wave=None,
+               uid=None) -> None:
+        pass
+
+    def count(self, name: str, n: int = 1) -> None:
+        pass
+
+    def to_host(self, tree) -> None:
+        pass
+
+    def to_device(self, *args) -> None:
+        pass
+
+    def attach(self) -> None:
+        pass
+
+    def detach(self) -> None:
+        pass
+
+
+DETACHED = _Detached()
 
 
 class Span:

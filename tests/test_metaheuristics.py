@@ -182,9 +182,11 @@ def test_state0_resume_continues_route():
 
 def test_placement_service_empty_input():
     from repro.core.flexai import FlexAIAgent, FlexAIConfig
-    from repro.serve.engine import FlexAIPlacementService
+    from repro.serve.qos import QoSConfig, QoSPlacementEngine
     plat = _platform()
     agent = FlexAIAgent(plat, FlexAIConfig(seed=1))
-    svc = FlexAIPlacementService(plat, agent.learner.eval_p)
-    assert svc.place([]) == []
-    assert svc.dispatches == 0
+    eng = QoSPlacementEngine(plat, agent.learner.eval_p,
+                             QoSConfig(policy="fifo"))
+    eng.run_until_done()
+    assert eng.dispatches == 0
+    assert eng.stats()["completed"] == 0

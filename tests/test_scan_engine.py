@@ -351,44 +351,49 @@ def test_train_vmapped_lanes_smoke():
 # serving path
 # ---------------------------------------------------------------------------
 
+def _serve_fifo(plat, params, backlog_scale, queues, slots=4):
+    """Serve ``queues`` through FIFO QoS waves; the engine and each
+    queue's request."""
+    from repro.serve.qos import QoSConfig, QoSPlacementEngine
+    eng = QoSPlacementEngine(
+        plat, params, QoSConfig(policy="fifo", slots=slots, min_bucket=64),
+        backlog_scale=backlog_scale)
+    reqs = [eng.submit(q) for q in queues]
+    eng.run_until_done()
+    return eng, reqs
+
+
 def test_placement_service_buckets_and_trims():
-    from repro.serve.engine import FlexAIPlacementService
     plat = _platform()
     agent = FlexAIAgent(plat, FlexAIConfig(seed=6))
-    svc = FlexAIPlacementService(plat, agent.learner.eval_p, min_bucket=64)
     queues = [_queue(41, km=0.02), _queue(42, km=0.03), _queue(43, km=0.02)]
-    results = svc.place(queues)
-    assert len(results) == len(queues)
-    for q, r in zip(queues, results):
-        assert r["tasks"] == len(q)
-        assert r["placements"].shape == (len(q),)
-        assert r["bucket"] >= len(q)
-    # same-bucket queues share a dispatch
-    assert svc.dispatches == len({r["bucket"] for r in results})
+    eng, reqs = _serve_fifo(plat, agent.learner.eval_p,
+                            agent.cfg.backlog_scale, queues)
+    assert len(reqs) == len(queues)
+    for q, r in zip(queues, reqs):
+        assert r.summary["tasks"] == len(q)
+        assert r.summary["placements"].shape == (len(q),)
+        assert r.summary["bucket"] >= len(q)
+    # same-bucket queues share a wave
+    assert eng.stats()["waves"] == len({r.summary["bucket"] for r in reqs})
 
 
-def test_placement_service_routes_tight_deadlines_to_fused_path():
-    """With a deadline vector, requests whose slack is under
-    ``tight_slack_s`` dispatch solo through the fused scan path and the
-    rest co-batch — with identical placements either way."""
-    from repro.serve.engine import FlexAIPlacementService
+def test_wave_lanes_place_as_if_served_alone():
+    """Each lane's scan is independent: three queues co-batched in one
+    four-lane wave get the placements and STM they get one per wave."""
     plat = _platform()
     agent = FlexAIAgent(plat, FlexAIConfig(seed=6))
     queues = [_queue(41, km=0.02), _queue(42, km=0.02), _queue(43, km=0.02)]
-    base = FlexAIPlacementService(plat, agent.learner.eval_p, min_bucket=64)
-    ref = base.place(queues)
-    svc = FlexAIPlacementService(plat, agent.learner.eval_p, min_bucket=64,
-                                 tight_slack_s=0.05)
-    results = svc.place(queues, deadlines=[0.01, 10.0, 10.0], now=0.0)
-    assert results[0]["path"] == "fused"
-    assert results[1]["path"] == results[2]["path"] == "batched"
-    assert svc.fused_dispatches == 1
-    for r, rr in zip(ref, results):
-        np.testing.assert_array_equal(r["placements"], rr["placements"])
-        assert r["stm_rate"] == pytest.approx(rr["stm_rate"], abs=1e-9)
-    # no deadline vector -> unchanged batched behaviour
-    plain = svc.place(queues)
-    assert all(r["path"] == "batched" for r in plain)
+    runs = {slots: _serve_fifo(plat, agent.learner.eval_p,
+                               agent.cfg.backlog_scale, queues, slots=slots)
+            for slots in (1, 4)}
+    assert runs[1][0].stats()["waves"] == len(queues)
+    assert runs[4][0].stats()["waves"] < len(queues)
+    for alone, batched in zip(runs[1][1], runs[4][1]):
+        np.testing.assert_array_equal(alone.summary["placements"],
+                                      batched.summary["placements"])
+        assert alone.summary["stm_rate"] == pytest.approx(
+            batched.summary["stm_rate"], abs=1e-9)
 
 
 # ---------------------------------------------------------------------------

@@ -36,6 +36,8 @@ _PIPE = []
 
 MODES = {
     "drain": dict(policy="fifo", slots=2, chunk=8, min_bucket=16),
+    "continuous": dict(policy="edf", slots=2, chunk=8, min_bucket=16,
+                       continuous=True),
     "preempt": dict(policy="edf", slots=2, chunk=8, min_bucket=16,
                     laxity_s=1e-4, aging_credit=0.0, shed=False),
     "pipeline": dict(policy="edf", slots=2, chunk=8, min_bucket=16,
@@ -134,6 +136,8 @@ def test_early_copy_serves_the_same_bytes(mode, monkeypatch):
     assert len(rec_on) == len(on.completed) > 0
     if mode == "preempt":
         assert on.preemption_count > 0
+    if mode == "continuous":
+        assert on.refills > 0
 
 
 def test_every_dispatch_starts_the_copy_of_what_the_seam_returned(
@@ -178,7 +182,7 @@ def test_start_host_copy_counts_device_leaves_only():
                                   np.arange(6).reshape(2, 3))
 
 
-@pytest.mark.parametrize("mode", ["drain", "pipeline"])
+@pytest.mark.parametrize("mode", ["continuous", "drain", "pipeline"])
 def test_d2h_early_counts_every_record_leaf_at_dispatch(mode):
     tr = Tracer()
     eng, _ = _serve(mode, tr)

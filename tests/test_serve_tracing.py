@@ -143,7 +143,9 @@ def test_span_tree_is_well_formed(mode):
         # one admission and one drain per drained wave
         assert len(admitted) == names.count("drain") == len(eng.wave_log)
     else:
-        assert names.count("drain") == len(eng.completed)
+        # lanes that end on one segment boundary drain together
+        assert names.count("drain") == len({r.finish for r in eng.completed})
+        assert names.count("drain.summarize") == len(eng.completed)
     s = tr.summary()["spans"]
     for name, e in s.items():
         assert e["self_ns"] == e["total_ns"] - sum(e["children"].values())

@@ -2,7 +2,6 @@
 pure re-layouts of the vmapped single-device engine.  Multi-device cases run
 in subprocesses (``--xla_force_host_platform_device_count`` must be set
 before jax imports); route-batch padding is covered in-process."""
-import json
 import os
 import subprocess
 import sys
@@ -104,33 +103,6 @@ def test_sharded_train_runs_and_lanes_differ():
         print("OK")
     """)
     out = _run_sub(script, devices=2)
-    assert "OK" in out
-
-
-@pytest.mark.slow
-def test_placement_service_sharded_matches_unsharded():
-    """FlexAIPlacementService on a 4-device mesh returns the same
-    placements and summaries as the single-device service."""
-    script = _PRELUDE + textwrap.dedent("""
-        from repro.serve.engine import FlexAIPlacementService
-        agent = FlexAIAgent(plat, FlexAIConfig(seed=6))
-        queues = [queue(41), queue(42, km=0.03), queue(43)]
-        base = FlexAIPlacementService(
-            plat, agent.learner.eval_p, min_bucket=64)
-        mesh = make_mesh((4,), ("routes",))
-        shard = FlexAIPlacementService(
-            plat, agent.learner.eval_p, min_bucket=64, mesh=mesh)
-        r_base = base.place(queues)
-        r_shard = shard.place(queues)
-        assert len(r_base) == len(r_shard) == len(queues)
-        for q, a, b in zip(queues, r_base, r_shard):
-            assert a["tasks"] == b["tasks"] == len(q)
-            assert np.array_equal(a["placements"], b["placements"])
-            assert abs(a["stm_rate"] - b["stm_rate"]) < 1e-9
-            assert abs(a["gvalue"] - b["gvalue"]) < 1e-6
-        print("OK", shard.dispatches)
-    """)
-    out = _run_sub(script, devices=4)
     assert "OK" in out
 
 
