@@ -578,6 +578,11 @@ class DurableQoSEngine(QoSPlacementEngine):
 
     # ---- durability seams ----------------------------------------------
 
+    def _segments_per_call(self, wave: Wave) -> int:
+        """One: the snapshot cadence, fault firing and the masked
+        executor act at every segment boundary."""
+        return 1
+
     def _dispatch_segment(self, wave: Wave, seg: TaskArrays):
         self._fire_due_faults()
         if self._stub or not self._use_masked:

@@ -70,12 +70,22 @@ Two production paths land on top (ISSUE 10):
   continuous waves run the one segment loop, ``_run_wave``: a drain
   wave's lanes all reach their end on its last segment.
 
+A jitted call serves many segments of a wave where the host acts on no
+boundary between them (``_segments_per_call``): a FIFO drain wave is two
+calls of half its segments, the second resuming from the state the
+first handed back; EDF with preemption, continuous, measured-clock,
+pipeline and durable waves keep one segment a call.  The clock is still
+charged per segment, so placements and the virtual clock do not depend
+on the cut.
+
 Tracing: ``engine.tracer = serve.tracing.Tracer()`` records the loop's
 spans — ``admit`` (children ``admit.pack_tasks``, ``admit.init_state``),
-``segment`` (``segment.slice``, ``segment.call``, ``hook`` around
-``_after_segment``), ``drain`` (``drain.records``, ``drain.state``,
-``drain.summarize``, ``hook`` around ``_on_complete``) and one ``queued``
-per request — with its transfer counters, ``waves_admitted``,
+``segment``, one per jitted call (``segment.slice``, ``segment.call``,
+``hook`` around ``_after_segment``), ``drain`` (``drain.records``,
+``drain.state``, ``drain.summarize``, ``hook`` around ``_on_complete``)
+and one ``queued`` per request — with its transfer counters,
+``waves_admitted``, ``segment_calls`` (one per jitted segment call;
+``dispatches`` counts the ``chunk``-task segments those calls served),
 ``fresh_state_reuses`` (fresh waves that took the engine's constant
 checkpoint in ``admit.init_state``; resumed waves and continuous refills
 do not count) and the requests' ``t_submit`` / ``t_admit`` / ``t_done``.
@@ -270,8 +280,9 @@ class Wave:
     """An admitted (and possibly checkpointed) lockstep wave.
 
     Each lane holds one occupant (or none) and the offset its occupant
-    has reached; ``recs`` holds the per-segment records some occupant
-    still needs.  A lane refilled at ``progress`` p holds its request's
+    has reached; ``recs`` holds the records of each jitted call (one
+    or more segments, ``[slots, columns]`` a leaf) some occupant still
+    needs.  A lane refilled at ``progress`` p holds its request's
     tasks rotated by p mod ``length``, so every lane's next tasks sit in
     the same columns and one slice per leaf builds each segment; a free
     lane holds invalid rows.  A wave built from a snapshot takes its lane
@@ -739,7 +750,7 @@ class QoSPlacementEngine:
         return jax.tree_util.tree_map(lambda a: a[: self.cfg.slots], out)
 
     def _timed_dispatch(self, wave: Wave, seg: TaskArrays):
-        """Dispatch one segment (the ``segment.call`` span), measuring
+        """Dispatch one call (the ``segment.call`` span), measuring
         wall time when the measured service clock is armed: the blocking
         ``perf_counter`` window feeds the per-(bucket, stages) EMA and is
         what ``_charge_segment`` advances the clock by for this
@@ -780,7 +791,8 @@ class QoSPlacementEngine:
             self.now += self.cfg.chunk * self.svc_step
 
     def _after_segment(self, wave: Wave) -> None:
-        """Segment-boundary hook: fault firing, heartbeats, snapshot
+        """Segment-boundary hook, once per jitted call, at the boundary
+        where the host can act: fault firing, heartbeats, snapshot
         cadence, preemption-guard checks (no-op in the base engine)."""
 
     def _on_complete(self, req: RouteRequest, lane_final, lane_recs) -> None:
@@ -791,12 +803,13 @@ class QoSPlacementEngine:
 
     def _run_wave(self, wave: Wave) -> None:
         """The segment loop of every wave, drain and continuous.  Each
-        pass serves one segment, completes the lanes that reached their
-        end and, under ``cfg.continuous``, sheds overrun lanes and
-        refills free ones; the wave leaves when no lane is occupied or
-        when it is preempted.  A drain wave's lanes all end on its last
-        segment; a wave restored after that segment completes without
-        serving another."""
+        pass serves one call (``_serve_segment``: one segment, or half a
+        wave's where the host acts on no boundary), completes the
+        lanes that reached their end and, under ``cfg.continuous``,
+        sheds overrun lanes and refills free ones; the wave leaves when
+        no lane is occupied or when it is preempted.  A drain wave's
+        lanes all end on its last segment; a wave restored after that
+        segment completes without serving another."""
         done = self._lanes_at_end(wave)
         while True:
             if not done:
@@ -810,11 +823,11 @@ class QoSPlacementEngine:
             if self.cfg.continuous:
                 self._shed_overrun_lanes(wave)
                 self._refill(wave)
-                # keep only the record segments an occupant still needs
+                # keep only the records an occupant still needs
                 need = max((o for o, r in zip(wave.lane_offsets,
                                               wave.lane_requests)
                             if r is not None), default=0)
-                del wave.recs[: len(wave.recs) - need // self.cfg.chunk]
+                del wave.recs[: self._recs_covering(wave, need)]
             if not wave.requests or self._preempt_if_due(wave):
                 return
 
@@ -834,17 +847,40 @@ class QoSPlacementEngine:
         return [lane for lane, r in enumerate(wave.lane_requests)
                 if r is not None and wave.lane_offsets[lane] >= wave.length]
 
+    def _segments_per_call(self, wave: Wave) -> int:
+        """Segments one jitted call serves.  A segment boundary exists
+        only where the host may act on it: EDF preemption, continuous
+        shedding and refill and the measured service clock act at every
+        boundary, and a pipeline wave's stage slice is cut per segment
+        (``_stage_slice``), so these serve one segment a call.
+        Otherwise a call serves half the wave's segments, at most the
+        rest: the wave's second call resumes from the state its first
+        handed back, so the placements, which the reference replay
+        checks, also show that the state is carried between calls — in
+        one call the scan's final state would reach only the summary."""
+        cfg = self.cfg
+        if (cfg.continuous or cfg.measured_svc or self.plan is not None
+                or (cfg.policy == "edf" and cfg.preempt)):
+            return 1
+        segments = wave.length // cfg.chunk
+        left = segments - (wave.progress % wave.length) // cfg.chunk
+        return min(left, -(-segments // 2))
+
     def _serve_segment(self, wave: Wave) -> None:
-        """Dispatch the wave's next ``chunk`` columns and advance the
-        wave, the clock and the arrivals; then the ``_after_segment``
-        hook."""
-        chunk = self.cfg.chunk
+        """Dispatch the wave's next ``k`` segments of ``chunk`` columns
+        in one call (``_segments_per_call``) and advance the wave; charge
+        the clock and promote arrivals once per segment, so the clock
+        reads as after ``k`` separate segments; then the
+        ``_after_segment`` hook, once, at the boundary the host can act
+        on."""
+        k = self._segments_per_call(wave)
+        cols = k * self.cfg.chunk
         tr = self._tracer
         with tr.span("segment", wave=len(self.wave_log) - 1):
             p = wave.progress % wave.length
             with tr.span("segment.slice"):
                 seg = jax.tree_util.tree_map(
-                    lambda a: a[:, p: p + chunk], wave.batch)
+                    lambda a: a[:, p: p + cols], wave.batch)
             state, recs = self._timed_dispatch(wave, seg)
             # the records travel to the host behind the device's compute
             # while the host dispatches on; the drain reads buffers that
@@ -852,15 +888,28 @@ class QoSPlacementEngine:
             early = _start_host_copy(recs)
             tr.to_host(recs)
             tr.count("d2h_early", early)
-            self.dispatches += 1
+            tr.count("segment_calls")
+            self.dispatches += k
             wave.state = state
             wave.recs.append(recs)
-            wave.progress += chunk
-            wave.lane_offsets = [o + chunk for o in wave.lane_offsets]
-            self._charge_segment(wave, recs)
-            self._promote_arrivals()
+            wave.progress += cols
+            wave.lane_offsets = [o + cols for o in wave.lane_offsets]
+            for _ in range(k):
+                self._charge_segment(wave, recs)
+                self._promote_arrivals()
             with tr.span("hook"):
                 self._after_segment(wave)
+
+    @staticmethod
+    def _recs_covering(wave: Wave, cols: int) -> int:
+        """Index of the first of the trailing ``wave.recs`` entries (one
+        per call, of one or more segments) that cover the last ``cols``
+        columns served."""
+        i, have = len(wave.recs), 0
+        while have < cols:
+            i -= 1
+            have += jax.tree_util.tree_leaves(wave.recs[i])[0].shape[1]
+        return i
 
     def _complete_lanes(self, wave: Wave, lanes: list) -> None:
         """Lanes that reached their end: bring the records they need and
@@ -871,11 +920,10 @@ class QoSPlacementEngine:
         tr = self._tracer
         with tr.span("drain", wave=len(self.wave_log) - 1):
             with tr.span("drain.records"):
-                segs = wave.recs[len(wave.recs)
-                                 - wave.length // self.cfg.chunk:]
+                i = self._recs_covering(wave, wave.length)
                 recs = jax.tree_util.tree_map(
                     lambda *xs: np.concatenate(xs, axis=1),
-                    *jax.device_get(segs))
+                    *jax.device_get(wave.recs[i:]))
             with tr.span("drain.state"):
                 tr.to_host(wave.state)
                 final = jax.device_get(wave.state)

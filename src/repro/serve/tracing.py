@@ -21,19 +21,24 @@ A :class:`Tracer` attached to a ``QoSPlacementEngine`` or a
 
 The counting rule: ``d2h_transfers`` / ``d2h_bytes`` count the
 ``jax.Array`` leaves the engine brings to the host, one transfer per
-leaf: a segment's records where their copy starts, at dispatch
+leaf: a call's records where their copy starts, at dispatch
 (``d2h_early`` counts these early starts alone), and the wave's state
 once per drain, when lanes complete; ``h2d_transfers`` /
 ``h2d_bytes`` count the ``np.ndarray`` leaves handed to a jitted
 segment call (params, task slice, state and, for pipeline waves, the
 stage slice and ring), each uploaded once by the call;
 ``waves_admitted`` counts admission rounds, one per ``wave_log`` entry;
+``segment_calls`` counts jitted segment calls, each of which serves one
+or more ``chunk``-task segments (the engine's ``dispatches`` counts
+those), so Δ``dispatches`` ÷ Δ``segment_calls`` is the segments a call
+serves (half a FIFO drain wave's: ``_segments_per_call`` in
+``serve/qos.py``);
 ``fresh_state_reuses`` counts the fresh waves that took the engine's
 constant initial checkpoint (a resumed wave keeps its own, and a
 continuous refill resets one lane), so in drain mode without
 preemptions it equals ``waves_admitted``.  Lanes that reach their end
 at one segment boundary drain together: in drain mode a wave drains
-once, so ``d2h_transfers`` is 10 record leaves a segment plus 11 state
+once, so ``d2h_transfers`` is 10 record leaves a call plus 11 state
 leaves a wave.
 In the trainer the same rule counts an episode's task arrays uploaded
 (``episode.upload``) and its records, losses and update mask brought to

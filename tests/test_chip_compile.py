@@ -133,13 +133,16 @@ def _segment_inputs(slots, chunk, p_sharding, lane_sharding):
             _shapes(state, lane_sharding))
 
 
-def test_qos_segment_compiles_for_v5e(one_chip, no_persistent_cache):
+@pytest.mark.parametrize("cols", ["chunk", 2048])
+def test_qos_segment_compiles_for_v5e(cols, one_chip, no_persistent_cache):
     """The serving hot path: one ``slots x chunk`` scan segment of the
-    FlexAI greedy engine over the real HMAI spec."""
+    FlexAI greedy engine over the real HMAI spec, and the one call that
+    serves half a 4096-task FIFO wave."""
     from repro.serve.qos import _segment_fn
     cfg = QoSConfig()
     seg = _segment_fn(spec_from_platform(HMAIPlatform()), 1.0)
-    args = _segment_inputs(cfg.slots, cfg.chunk, one_chip, one_chip)
+    args = _segment_inputs(cfg.slots, cfg.chunk if cols == "chunk" else cols,
+                           one_chip, one_chip)
     compiled = seg.lower(*args).compile()
     assert compiled.memory_analysis() is not None
 

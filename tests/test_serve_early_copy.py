@@ -1,13 +1,13 @@
 """Record copies to the host started at dispatch (``serve/qos.py``).
 
 ``_run_wave`` starts the device-to-host copy of every record leaf a
-segment returns (``_start_host_copy``) and the drain reads the landed
+jitted call returns (``_start_host_copy``) and the drain reads the landed
 buffers.  The early copy changes when the record bytes travel, never
 which: every executor's served records, summaries and serving digest
 equal a run in which the helper is disabled and the drain fetches
 everything itself.  With a tracer the record leaves are counted where
 their copy starts (``d2h_early``), and ``d2h_transfers`` keeps its
-arithmetic: 10 record leaves per segment plus 11 state leaves per wave.
+arithmetic: 10 record leaves per call plus 11 state leaves per wave.
 """
 import os
 import subprocess
@@ -142,7 +142,7 @@ def test_early_copy_serves_the_same_bytes(mode, monkeypatch):
 
 def test_every_dispatch_starts_the_copy_of_what_the_seam_returned(
         monkeypatch):
-    """One start per segment, on the records ``_dispatch_segment``
+    """One start per jitted call, on the records ``_dispatch_segment``
     returned: executors swapped in at that seam get it unchanged."""
     started, returned = [], []
     real = qos._start_host_copy
@@ -163,10 +163,12 @@ def test_every_dispatch_starts_the_copy_of_what_the_seam_returned(
         return out
 
     eng._dispatch_segment = dispatch
+    eng.tracer = Tracer()
     for i in range(3):
         eng.submit(_route(20 + i, i), deadline=100.0)
     eng.run_until_done()
-    assert len(started) == len(returned) == eng.dispatches > 0
+    calls = eng.tracer.counters["segment_calls"]
+    assert len(started) == len(returned) == calls > 0
     assert all(s is r for s, r in zip(started, returned))
     assert all(isinstance(x, jax.Array)
                for r in returned for x in jax.tree_util.tree_leaves(r))
@@ -189,8 +191,9 @@ def test_d2h_early_counts_every_record_leaf_at_dispatch(mode):
     c = tr.summary()["counters"]
     waves = len(eng.wave_log)
     n_rec = len(StepRecord._fields)
-    assert c["d2h_early"] == n_rec * eng.dispatches > 0
-    assert c["d2h_transfers"] == (n_rec * eng.dispatches
+    calls = c["segment_calls"]
+    assert c["d2h_early"] == n_rec * calls > 0
+    assert c["d2h_transfers"] == (n_rec * calls
                                   + len(PlatformState._fields) * waves)
     # each start is logged inside a segment span, none inside a drain
     segs = [(sp.start_ns, sp.end_ns) for sp in tr.spans
@@ -198,7 +201,7 @@ def test_d2h_early_counts_every_record_leaf_at_dispatch(mode):
     drains = [(sp.start_ns, sp.end_ns) for sp in tr.spans
               if sp.name == "drain"]
     early = [t for t, name, _ in tr._log if name == "d2h_early"]
-    assert len(early) == eng.dispatches
+    assert len(early) == calls
     assert all(any(a <= t <= b for a, b in segs) for t in early)
     assert not any(a <= t <= b for a, b in drains for t in early)
 
@@ -315,8 +318,9 @@ _MESH_SCRIPT = textwrap.dedent("""
     qos._start_host_copy = real
     assert digests_equal(serving_digest(on), serving_digest(off))
     assert rec_on == rec_off and len(rec_on) == 5
-    early = on.tracer.summary()["counters"]["d2h_early"]
-    assert early == 10 * on.dispatches > 0, (early, on.dispatches)
+    c = on.tracer.summary()["counters"]
+    early, calls = c["d2h_early"], c["segment_calls"]
+    assert early == 10 * calls > 0, (early, calls)
     print("OK", on.dispatches)
 """)
 

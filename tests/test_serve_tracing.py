@@ -126,8 +126,9 @@ def test_span_tree_is_well_formed(mode):
             assert p.start_ns <= sp.start_ns and sp.end_ns <= p.end_ns
             assert sp.wave == p.wave or p.name == "admit" or sp.name == "gc"
     names = [sp.name for sp in spans]
-    assert names.count("segment") == eng.dispatches
-    assert names.count("segment.call") == eng.dispatches
+    calls = tr.counters["segment_calls"]
+    assert names.count("segment") == calls <= eng.dispatches
+    assert names.count("segment.call") == calls
     uids = [u for w in eng.wave_log for u in w]
     assert sorted(sp.uid for sp in spans if sp.name == "queued") == sorted(
         uids)
@@ -160,8 +161,9 @@ def test_counters_equal_the_shape_arithmetic():
     c = tr.summary()["counters"]
     waves = len(eng.wave_log)
     n_rec, n_state = len(StepRecord._fields), len(PlatformState._fields)
+    calls = c["segment_calls"]
     assert c["waves_admitted"] == waves
-    assert c["d2h_transfers"] == n_rec * eng.dispatches + n_state * waves
+    assert c["d2h_transfers"] == n_rec * calls + n_state * waves
     lanes, chunk, n_acc = eng.cfg.slots, eng.cfg.chunk, eng.spec.n
     rec_bytes = lanes * chunk * sum(
         4 if f not in ("met", "valid") else 1 for f in StepRecord._fields)
@@ -170,7 +172,7 @@ def test_counters_equal_the_shape_arithmetic():
     assert c["d2h_bytes"] == rec_bytes * eng.dispatches + state_bytes * waves
     # the task slice (kind, arrival, safety, group, valid) is uploaded
     # with each call; params and state already live on the device
-    assert c["h2d_transfers"] == len(TaskArrays._fields) * eng.dispatches
+    assert c["h2d_transfers"] == len(TaskArrays._fields) * calls
     assert c["h2d_bytes"] == lanes * chunk * (4 + 4 + 4 + 4 + 1) \
         * eng.dispatches
     assert tr.counters == c
